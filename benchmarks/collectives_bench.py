@@ -25,8 +25,12 @@ RESULTS = os.path.join(os.path.dirname(__file__), "results")
 PSUM_FMTS = ("f32", "bf16", "t16", "t8", "e4m3", "e5m2", "mxe4m3", "mxt8")
 PIPE_FMTS = ("t8", "t16", "e4m3", "bf16", "mxe4m3")
 
+# the child measures numerics on 8 virtual CPU devices; it is pinned to the
+# CPU so that, on a machine with a TPU, it never reaches for the chip that
+# the parent process holds
 _CHILD = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
@@ -97,6 +101,7 @@ def run(smoke: bool = False):
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "../src")
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, "-c", child],
         env=env, capture_output=True, text=True, timeout=560,

@@ -17,7 +17,9 @@ timeline of kernel-dispatch, collective-hop, and train-step spans.
 
 import os
 
-# must precede the jax import: the pod mesh needs 8 (fake) devices
+# must precede the jax import: the pod mesh needs 8 virtual CPU devices, and
+# the demo stays on the CPU even where a TPU is attached
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402

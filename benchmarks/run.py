@@ -156,6 +156,9 @@ def _validate_bench_json(smoke: bool, fold_keys: set) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     quick = "--quick" in sys.argv
     smoke = "--smoke" in sys.argv
     emit_json = "--json" in sys.argv

@@ -190,7 +190,8 @@ def decode_jnp(bits, fmt: str = "e4m3"):
     b = bits.astype(_U)
     sign = (b >> 7) & 1
     e_f = ((b >> mb) & ((1 << eb) - 1)).astype(jnp.int32)
-    m_f = (b & ((1 << mb) - 1)).astype(jnp.float32)
+    # via int32: Mosaic has no uint32 -> f32 conversion
+    m_f = (b & ((1 << mb) - 1)).astype(jnp.int32).astype(jnp.float32)
 
     normal = (1.0 + m_f * (2.0**-mb)) * _pow2_f32(e_f - bias)
     subn = m_f * (2.0**-mb) * _pow2_f32(jnp.full_like(e_f, 1 - bias))

@@ -13,17 +13,10 @@ Modules (import explicitly; only the lightweight ones load eagerly):
 * :mod:`~repro.dist.pipeline` — GPipe-style microbatched stage execution.
 * :mod:`~repro.dist.error_feedback` — residual-carrying compressed psum.
 
-Importing the package installs the jax 0.4.x compatibility adapters
-(``jax.shard_map`` / ``jax.lax.pvary``) via :mod:`~repro.dist._compat`; on a
-modern jax that is a no-op.  ``step`` and ``sharding`` are *not* imported
-here to keep the models -> actx -> dist import chain acyclic (step imports
-the models).
+``step`` and ``sharding`` are *not* imported here to keep the models ->
+actx -> dist import chain acyclic (step imports the models).
 """
 
-from . import _compat
-
-_compat.install()
-
-from . import actx  # noqa: E402  (needs the compat install above)
+from . import actx
 
 __all__ = ["actx"]

@@ -114,6 +114,19 @@ def axis_size(axis_name) -> int:
     return jax.lax.psum(1, axis_name)
 
 
+def varying(tree, axis_name):
+    """Mark every leaf of ``tree`` as varying over ``axis_name`` (leaves
+    that already vary pass through).  The two arms of a ``lax.cond`` must
+    agree on it, and a psum'd or constant leaf is invariant where a ring
+    result varies.  Outside ``check_vma`` shard_maps this is the identity."""
+
+    def one(x):
+        x = jnp.asarray(x)
+        return x if axis_name in jax.typeof(x).vma else jax.lax.pvary(x, axis_name)
+
+    return jax.tree.map(one, tree)
+
+
 def _ring_reduce(wire, own_f32, axis_name, decode, N: int,
                  canonical_order: bool = True, contain_abs=None,
                  fmt_name: str = "wire"):
@@ -308,7 +321,11 @@ def degraded_psum(x, axis_name, fmt, guard, *, exact_local: bool = True,
             trip_local = (spec > guard.max_special_frac) | (rel > guard.max_rel_err)
             # uniform trip decision BEFORE the branch (see docstring)
             trip = jax.lax.psum(trip_local.astype(jnp.float32), axis_name) > 0
-            return jax.lax.cond(trip, lambda: attempt(i + 1), ring)
+            return jax.lax.cond(
+                trip,
+                lambda: varying(attempt(i + 1), axis_name),
+                lambda: varying(ring(), axis_name),
+            )
 
         out, rung, contained = attempt(0)
 

@@ -33,14 +33,19 @@ from repro.core import telemetry
 from repro.core.formats import special_fraction, wire_format
 from repro.quant import blockscale
 
-from .collectives import _ring_reduce, axis_size, wire_codec
+from .collectives import _ring_reduce, axis_size, varying, wire_codec
 
 IS_STUB = False
 
 
 def ef_init(params):
-    """Per-leaf f32 error accumulator pytree, zero-initialised."""
-    return jax.tree.map(lambda a: jnp.zeros(jnp.shape(a), jnp.float32), params)
+    """Per-leaf f32 error accumulator pytree, zero-initialised.
+
+    ``zeros_like`` keeps each leaf's varying mesh axes, so a state built
+    inside ``shard_map`` from per-device gradients has the type the EF step
+    returns — a ``lax.scan`` carry must keep its type across iterations.
+    """
+    return jax.tree.map(lambda a: jnp.zeros_like(a, dtype=jnp.float32), params)
 
 
 def ef_compressed_psum(g, err, axis_name, fmt="t8", guard=None):
@@ -138,7 +143,11 @@ def ef_compressed_psum(g, err, axis_name, fmt="t8", guard=None):
             trip_local = (spec > guard.max_special_frac) | (rel > guard.max_rel_err)
             # ring-uniform escalation: psum the trip BEFORE branching
             trip = jax.lax.psum(trip_local.astype(jnp.float32), axis_name) > 0
-            return jax.lax.cond(trip, lambda: at_rung(i + 1), send)
+            return jax.lax.cond(
+                trip,
+                lambda: varying(at_rung(i + 1), axis_name),
+                lambda: varying(send(), axis_name),
+            )
 
         reduced, new_err, rung, contained_ = at_rung(0)
         telemetry.emit("ef.calls", jnp.float32(1))

@@ -32,7 +32,6 @@ from repro.core import telemetry
 from repro.core.formats import special_fraction, wire_format
 
 from . import faults
-from ._compat import shard_map
 
 IS_STUB = False
 
@@ -205,7 +204,7 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str = "pipe",
                     sp.dep = telemetry.probe(recv)
         return jax.lax.psum(out_buf, axis)
 
-    fn = shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(), check_rep=False
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(), check_vma=False
     )
     return fn(stage_params, x)

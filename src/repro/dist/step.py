@@ -25,7 +25,6 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import telemetry
 from repro.core.formats import wire_format
@@ -37,7 +36,6 @@ from repro.quant.qtensor import QTensor, dequantize, quantize
 from . import actx
 from . import faults
 from . import sharding as shd
-from ._compat import shard_map
 from .collectives import compressed_pmean, degraded_pmean
 
 IS_STUB = False
@@ -64,7 +62,8 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                     master_dtype=jnp.float32):
     """Build ``step(state, batch) -> (state, metrics)`` for ``cfg`` on ``mesh``.
 
-    Metrics: ``loss`` (ce + aux), ``ce``, ``aux`` — all scalars.  On meshes
+    Metrics: ``loss`` (ce + aux), ``ce``, ``aux``, ``grad_norm`` (global L2
+    norm of the reduced gradients) — all scalars.  On meshes
     with a nontrivial "pod" axis the gradient mean over pods runs through
     ``compressed_pmean`` in ``cfg.quant.grad_comm`` format; everything else
     (data-parallel reduction, TP psums) is GSPMD under jit.
@@ -99,33 +98,34 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                         wire_key, jax.lax.axis_index("pod")
                     )
 
-                # one flat payload -> one data-axis pmean + ONE compressed
-                # ring, not one per leaf: the codec is element-wise so the
-                # numerics are identical, but P-1 large messages beat
-                # leaves*(P-1) tiny latency-bound ones on a real interconnect
-                flat, treedef = jax.tree.flatten(grads)
-                sizes = [g.size for g in flat]
-                payload = jnp.concatenate(
-                    [g.astype(jnp.float32).ravel() for g in flat]
-                )
                 # raw-gradient health, checked BEFORE any containment zeroes
                 # the evidence: pmean'd into the [0,1] fraction of devices
                 # whose local grads were all-finite (1.0 = clean step)
-                grads_ok = jnp.isfinite(payload).all().astype(jnp.float32)
+                flat, treedef = jax.tree.flatten(grads)
+                grads_ok = jnp.float32(1)
+                for g in flat:
+                    grads_ok = grads_ok * jnp.isfinite(g).all().astype(jnp.float32)
                 if data_axes:
-                    payload = jax.lax.pmean(payload, data_axes)
-                sr_key = wire_key if wire_sr else None
-                if guard is None:
-                    payload = compressed_pmean(payload, "pod", fmt, sr_key=sr_key)
-                else:
-                    payload = degraded_pmean(
-                        payload, "pod", fmt, guard, sr_key=sr_key
-                    )
-                parts = jnp.split(payload, list(np.cumsum(sizes))[:-1])
+                    flat = jax.lax.pmean(flat, data_axes)
+
+                # one data-axis pmean and one compressed ring per leaf.  A
+                # single flat payload (all leaves concatenated into one 1-D
+                # array) gives the same numerics, but the TPU compiler takes
+                # minutes over the concatenate/split at model size and
+                # overflows its stack at 8 hymba_1_5b layers.  Each leaf's
+                # SR key is its own, so the noise is independent across
+                # leaves as it was across the flat payload.
+                def reduce(i, g):
+                    key = jax.random.fold_in(wire_key, i) if wire_sr else None
+                    g32 = g.astype(jnp.float32)
+                    if guard is None:
+                        out = compressed_pmean(g32, "pod", fmt, sr_key=key)
+                    else:
+                        out = degraded_pmean(g32, "pod", fmt, guard, sr_key=key)
+                    return out.astype(g.dtype)
+
                 grads = jax.tree.unflatten(
-                    treedef,
-                    [p.reshape(g.shape).astype(g.dtype)
-                     for p, g in zip(parts, flat)],
+                    treedef, [reduce(i, g) for i, g in enumerate(flat)]
                 )
                 loss = jax.lax.pmean(loss, batch_axes)
                 metrics = {**metrics, "grad_ok": grads_ok}
@@ -146,10 +146,10 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                     f"global batch {B} must divide by the pod axis "
                     f"({mesh.shape['pod']}) for compressed pod reduction"
                 )
-            return shard_map(
+            return jax.shard_map(
                 fwd_bwd_local(axes), mesh=mesh,
                 in_specs=(P(), P(axes), P()), out_specs=(P(), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )(params, batch, wire_key)
     else:
 
@@ -174,6 +174,12 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
         with telemetry.trace_span("step.train", cat="step") as sp:
             rng, sr_key, wire_key = jax.random.split(state.rng, 3)
             loss, metrics, grads = fwd_bwd(state.params, batch, wire_key)
+            # global L2 norm of the reduced gradients (after the compressed
+            # ring on pod meshes)
+            gn = jnp.sqrt(sum(
+                jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads)
+            ))
             if telemetry.enabled():
                 # one record per *execution* of this trace (the step runs
                 # outside shard_map, so multiplicity is 1, not n_devices)
@@ -181,17 +187,14 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                 tok = batch.get("tokens")
                 if tok is not None:
                     telemetry.emit("step.tokens", float(tok.shape[0] * tok.shape[1]))
-                gn = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)
-                ))
                 telemetry.emit_hist("step.grad_norm", gn)
             use_sr = cfg.quant.stochastic_rounding and is_takum(cfg.quant.opt_state)
             new_params, new_opt = adamw_update(
                 grads, state.opt, state.params, lr=lr, fmt=cfg.quant.opt_state,
                 key=sr_key if use_sr else None,
             )
-            out = {"loss": loss, "ce": metrics["ce"], "aux": metrics["aux"]}
+            out = {"loss": loss, "ce": metrics["ce"], "aux": metrics["aux"],
+                   "grad_norm": gn}
             guard = cfg.quant.guard
             if guard is not None and guard.skip_nonfinite_update:
                 # GradScaler-style microbatch skip: a step whose raw gradients
@@ -306,13 +309,21 @@ def serve_param_shapes(cfg):
     )
 
 
-def make_prefill_step(cfg, mesh):
-    """``step(params, batch) -> (last_logits, cache)`` (quantised weights)."""
+def make_prefill_step(cfg, mesh, *, cache_len: int | None = None):
+    """``step(params, batch) -> (last_logits, cache)`` (quantised weights).
+
+    ``cache_len`` is the prompt length plus the decode budget: the returned
+    cache has that many positions, so each :func:`make_serve_step` call
+    after it writes a free slot.  Left at None the cache is exactly the
+    prompt long and holds no room for decoding.
+    """
 
     def step(params, batch):
         p = dequantize_params(params)
         with actx.use_mesh(mesh):
-            return T.prefill(cfg, p, batch["tokens"], batch.get("media"))
+            return T.prefill(
+                cfg, p, batch["tokens"], batch.get("media"), cache_len=cache_len
+            )
 
     return step
 

@@ -40,6 +40,12 @@ def choose_block(dim: int, want: int, align: int) -> int:
     return min(want, round_up(dim, align))
 
 
+def sublane_align(dtype) -> int:
+    """Row alignment of a tile of ``dtype`` on TPU: 8 sublanes of 32-bit
+    words, so 16 rows for 16-bit and 32 rows for 8-bit packed operands."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def dim_mask(tile_shape, axis: int, dim: int, block: int, pid):
     """Edge-tile validity mask: True where global index along ``axis`` < dim.
 
@@ -87,7 +93,7 @@ def decode_takum_f32(bits, n: int):
     sat_hi = c > 127
     flush = c < -126
     e_fld = (jnp.clip(c, -126, 127) + 127).astype(_U)
-    m_fld = M << jnp.minimum((23 - p).astype(_U), _U(23))
+    m_fld = M << jnp.clip(23 - p, 0, 23).astype(_U)
     out = (e_fld << 23) | m_fld
     out = jnp.where(sat_hi, _U(0x7F7FFFFF), out)
     out = jnp.where(flush | is_zero, _U(0), out)
@@ -131,21 +137,25 @@ def encode_takum_from_f32(x, n: int):
     # body = H:m23 left-aligned; round to keep n-1 bits (t = 28 + r - n <= 27)
     hi = H >> 9
     lo = ((H & _U(0x1FF)) << 23) | m23
+    # shift amounts are computed in int32 and cast at the shift: Mosaic has
+    # no unsigned min/max (arith.minui/maxui do not legalize on TPU)
     t = (28 + r - n).astype(_I)
-    tc = jnp.maximum(t, 1).astype(_U)
-    up_sh = jnp.minimum(_U(32) - tc, _U(31))
-    kept = jnp.where(t == 0, lo, (lo >> jnp.minimum(tc, _U(31))) | (hi << up_sh))
+    tc = jnp.maximum(t, 1)
+    up_sh = jnp.minimum(32 - tc, 31).astype(_U)
+    kept = jnp.where(t == 0, lo, (lo >> jnp.minimum(tc, 31).astype(_U)) | (hi << up_sh))
     g1 = tc - 1
     guard = jnp.where(
-        g1 >= 32, (hi >> jnp.minimum(g1 - _U(32), _U(31))) & 1, (lo >> jnp.minimum(g1, _U(31))) & 1
+        g1 >= 32,
+        (hi >> jnp.clip(g1 - 32, 0, 31).astype(_U)) & 1,
+        (lo >> jnp.minimum(g1, 31).astype(_U)) & 1,
     )
     guard = jnp.where(t >= 1, guard, _U(0))
-    below = jnp.where(g1 == 0, _U(0), (_U(1) << jnp.minimum(g1, _U(31))) - 1)
+    below = jnp.where(g1 == 0, _U(0), (_U(1) << jnp.minimum(g1, 31).astype(_U)) - 1)
     sticky = (lo & below) != 0
     round_up = (guard == 1) & (sticky | ((kept & 1) == 1))
     mag = kept + round_up.astype(_U)
     # t < 0 impossible for n <= 28 with f32 input (t = 28 + r - n, r >= 0)
-    mag = jnp.clip(mag, _U(1), _U((1 << (n - 1)) - 1))
+    mag = jnp.clip(mag.astype(_I), 1, (1 << (n - 1)) - 1).astype(_U)
 
     enc = jnp.where(neg_in == 1, (_U(0) - mag) & _U((1 << n) - 1), mag)
     enc = jnp.where(is_zero, _U(0), enc)

@@ -104,6 +104,18 @@ def resolve_impl(impl: str | None, fmt, op: str = "decode") -> str:
     tabulable = wf.supports_lut_decode if op == "decode" else wf.supports_lut_encode
     if impl == "lut" and not tabulable:
         raise ValueError(f"{op}_impl='lut': no tables for {wf.name} ({wf.nbits}b)")
+    if (
+        impl == "lut"
+        and op == "decode"
+        and (1 << wf.nbits) > LANE_GATHER_MAX_ROWS * 128
+        and jax.default_backend() == "tpu"
+    ):
+        raise ValueError(
+            f"decode_impl='lut' for {wf.name}: its {1 << wf.nbits}-entry table "
+            f"cannot be gathered on TPU (Mosaic gathers within one 128-lane "
+            f"vreg; tables up to {LANE_GATHER_MAX_ROWS * 128} entries are "
+            f"gathered row by row) — use decode_impl='bits'"
+        )
     return impl
 
 
@@ -145,21 +157,39 @@ def wire_decode_fn(fmt, impl, tab_ref=None):
     ``impl == "lut"`` gathers from ``tab_ref`` (the decode-table operand ref
     for the format — the *element* format's table for block-scaled
     containers); ``"bits"`` is the branch-free family decode.  For
-    block-scaled formats the closure consumes an interleaved payload tile
-    ``[..., nb*33]`` — the scale bytes ride in the same VMEM block — and
-    emits ``[..., nb*32]`` f32.
+    block-scaled formats the closure takes the split operand form
+    (:func:`repro.quant.blockscale.split_payload`): ``(bits, sb)`` tiles of
+    element bits and per-element scale bytes, both ``[..., n]``.
     """
+    wf = wire_format(fmt)
     if impl == "lut":
         inner = lambda bits: decode_wire_lut(tab_ref[...], bits)
     else:
-        inner = None
-    wf = wire_format(fmt)
+        inner = decode_bits_fn(wf.elem_name if wf.is_block_scaled else wf.name)
     if wf.is_block_scaled:
-        elem_dec = inner if inner is not None else decode_bits_fn(wf.elem_name)
-        return lambda payload: blockscale.decode_payload(
-            payload, wf, elem_decode=elem_dec
+        return lambda bits, sb: blockscale.dequantize_elems(
+            bits, sb, wf, elem_decode=inner
         )
-    return inner if inner is not None else decode_bits_fn(wf.name)
+    return inner
+
+
+def wire_encode_fn(fmt, impl, enc_tab_refs=()):
+    """The tile-encode closure of the codec kernel: f32 tile -> uint codes.
+
+    ``enc_tab_refs`` are the LUT operand refs (empty for the bits impl).
+    For block-scaled formats the closure takes ``(x, sb)`` — the tile and
+    its per-element scale bytes, derived by XLA around the kernel — and
+    returns element bits (the split form, see :func:`wire_decode_fn`).
+    """
+    wf = wire_format(fmt)
+    name = wf.elem_name if wf.is_block_scaled else wf.name
+    if impl == "lut":
+        inner = lambda v: encode_wire_lut(v, tuple(t[...] for t in enc_tab_refs), name)
+    else:
+        inner = encode_bits_fn(name)
+    if wf.is_block_scaled:
+        return lambda x, sb: blockscale.quantize_elems(x, sb, wf, elem_encode=inner)
+    return inner
 
 
 def decode_table_operand(fmt):
@@ -185,14 +215,51 @@ def encode_table_operands(fmt):
     return tuple(jnp.asarray(t).reshape(-1, 128) for t in encode_tables(name))
 
 
+#: tables of at most this many 128-lane rows are gathered lane-wise (the
+#: 256-entry byte-indexed tables); larger ones (the 65536-entry takum16
+#: decode table) only through ``jnp.take``, which Mosaic cannot lower
+LANE_GATHER_MAX_ROWS = 2
+
+
+def table_lookup(tab, idx):
+    """Per-element table gather ``tab.flat[idx]`` in a form Mosaic lowers.
+
+    A 1-D ``tab`` (the XLA-side jnp codecs) is a plain ``jnp.take``.  A
+    lanes-major ``(rows, 128)`` operand (the in-kernel form) with at most
+    :data:`LANE_GATHER_MAX_ROWS` rows is gathered one 128-lane row at a
+    time: Mosaic only lowers a 2-D ``take_along_axis`` whose source is a
+    single vreg along the gathered axis, so each table row is broadcast
+    over the index tile (viewed as ``[-1, 128]``), gathered by the low 7
+    index bits and selected by the high bits.  ``idx`` tiles whose last dim
+    is not a multiple of 128 (interpret-mode only) fall back to the take.
+    """
+    idx = idx.astype(jnp.int32)
+    if (
+        tab.ndim != 2
+        or tab.shape[0] > LANE_GATHER_MAX_ROWS
+        or idx.ndim == 0
+        or idx.shape[-1] % 128
+    ):
+        return jnp.take(tab.reshape(-1), idx, axis=0)
+    i2 = idx.reshape(-1, 128)
+    lane, row = i2 & 127, i2 >> 7
+    out = None
+    for r in range(tab.shape[0]):
+        src = jnp.broadcast_to(tab[r : r + 1, :], i2.shape)
+        g = jnp.take_along_axis(src, lane, axis=1)
+        out = g if out is None else jnp.where(row == r, g, out)
+    return out.reshape(idx.shape)
+
+
 def decode_wire_lut(tab, bits):
     """Gather-based wire decode: uint patterns -> float32 values.
 
-    ``tab`` is the (possibly 2D-shaped) f32 decode table for the same
-    format as ``bits``; the mapping is a pure per-element gather — zero,
-    NaR/NaN/Inf and negative patterns are all just table rows.
+    ``tab`` is the f32 decode table for the same format as ``bits`` (1-D,
+    or the ``(rows, 128)`` kernel operand); the mapping is a pure
+    per-element gather — zero, NaR/NaN/Inf and negative patterns are all
+    just table rows.
     """
-    return jnp.take(tab.reshape(-1), bits.astype(jnp.int32), axis=0)
+    return table_lookup(tab, bits)
 
 
 #: back-compat alias (PR-1 name; the gather was never takum-specific)
@@ -241,8 +308,8 @@ def encode_takum8_lut(x, meta, thr):
 
     e = (a >> 23).astype(jnp.int32)
     m23 = (a & _U(0x7FFFFF)).astype(jnp.int32)
-    mt = jnp.take(meta.reshape(-1), e, axis=0)
-    t = jnp.take(thr.reshape(-1), e, axis=0)
+    mt = table_lookup(meta, e)
+    t = table_lookup(thr, e)
 
     mag = _round_shift_or_threshold(m23, mt, t)
     enc = jnp.where(neg == 1, (_U(0) - mag) & _U(0xFF), mag)
@@ -267,11 +334,12 @@ def encode_ofp8_lut(x, meta, thr, fmt: str):
 
     e = (a >> 23).astype(jnp.int32)
     m23 = (a & _U(0x7FFFFF)).astype(jnp.int32)
-    mt = jnp.take(meta.reshape(-1), e, axis=0)
-    t = jnp.take(thr.reshape(-1), e, axis=0)
+    mt = table_lookup(meta, e)
+    t = table_lookup(thr, e)
 
     mag = _round_shift_or_threshold(m23, mt, t)
-    mag = jnp.minimum(mag, ovf)  # top-binade carry past the last finite code
+    # top-binade carry past the last finite code (signed: Mosaic has no minui)
+    mag = jnp.minimum(mag.astype(jnp.int32), jnp.int32(ofp8_overflow_code(fmt))).astype(_U)
     mag = jnp.where(is_inf, ovf, mag)  # E4M3: Inf -> NaN (ovf *is* the NaN)
     mag = jnp.where(is_nan, _U(0x7F), mag)
     return ((sign << 7) | mag).astype(_U)
@@ -309,9 +377,9 @@ def encode_takum16_lut(x, meta, sub):
 
     e = (a >> 23).astype(jnp.int32)
     m23 = a & _U(0x7FFFFF)
-    mt = jnp.take(meta.reshape(-1), e, axis=0)
+    mt = table_lookup(meta, e)
     base = mt >> 8
-    s = jnp.take(sub.reshape(-1), (mt & _U(0xFF)).astype(jnp.int32), axis=0).astype(_U)
+    s = table_lookup(sub, mt & _U(0xFF)).astype(_U)
     mag = _shift_round_rne(base, s, m23)
 
     enc = jnp.where(neg == 1, (_U(0) - mag) & _U(0xFFFF), mag)
@@ -365,20 +433,13 @@ def encode_epilogue(out_fmt, out_impl, enc_tab_refs):
     whole-array encode (tiles are 128-aligned, so this always holds)."""
     wf = wire_format(out_fmt)
     if wf.is_block_scaled:
-        if out_impl == "lut":
-            elem_enc = lambda v: encode_wire_lut(
-                v, tuple(t[...] for t in enc_tab_refs), wf.elem_name
-            )
-        else:
-            elem_enc = encode_bits_fn(wf.elem_name)
+        elem_enc = wire_encode_fn(wf.elem_name, out_impl, enc_tab_refs)
         # the cap-clip inside block_quantize runs before elem_enc, so the
-        # non-saturating LUT/bit element encoders are exact here
+        # non-saturating LUT/bit element encoders are exact here.  The
+        # payload is interleaved in-kernel, which only the interpreter
+        # lowers (Mosaic cannot split lanes into 33-byte groups)
         return lambda acc: blockscale.encode_payload(acc, wf, elem_encode=elem_enc)
-    if out_impl == "lut":
-        return lambda acc: encode_wire_lut(
-            acc, tuple(t[...] for t in enc_tab_refs), out_fmt
-        )
-    return encode_bits_fn(out_fmt)
+    return wire_encode_fn(out_fmt, out_impl, enc_tab_refs)
 
 
 def encode_epilogue_operands(out_fmt, out_impl):

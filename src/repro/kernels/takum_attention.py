@@ -39,7 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import wire_format
 from repro.quant import blockscale
-from .common import choose_block, dim_mask, interpret_default, round_up
+from .common import choose_block, dim_mask, interpret_default, round_up, sublane_align
 from .lut import (
     decode_table_operand,
     encode_epilogue,
@@ -56,9 +56,12 @@ _SUBLANE = 8
 def _decode_attn_kernel(fmt, impl, S, bs, g, d, scale, out_fmt, out_impl, nenc, *refs):
     ndec = 1 if impl == "lut" else 0
     enc_tabs = refs[ndec : ndec + nenc]
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[ndec + nenc :]
-    decode = wire_decode_fn(fmt, impl, refs[0] if impl == "lut" else None)
     mx = wire_format(fmt).is_block_scaled
+    nkv = 2 if mx else 1  # block-scaled K/V come as (bits, scale bytes)
+    opnds = refs[ndec + nenc :]
+    q_ref, k_refs, v_refs = opnds[0], opnds[1 : 1 + nkv], opnds[1 + nkv : 1 + 2 * nkv]
+    o_ref, m_ref, l_ref, acc_ref = opnds[1 + 2 * nkv :]
+    decode = wire_decode_fn(fmt, impl, refs[0] if impl == "lut" else None)
     out_mx = out_fmt is not None and wire_format(out_fmt).is_block_scaled
 
     s = pl.program_id(2)
@@ -76,26 +79,30 @@ def _decode_attn_kernel(fmt, impl, S, bs, g, d, scale, out_fmt, out_impl, nenc, 
         # are dropped by the clipped output store)
         q = jnp.where(dim_mask(q.shape, 0, g, gp, 0), q, 0.0)
     if dp != d:
-        # padded d lanes: q cols -> 0.0, K/V cols -> bits 0 -> decode 0.0,
-        # so every contraction only gains exact-zero terms
+        # padded d lanes: q cols -> 0.0, K/V cols -> 0.0, so every
+        # contraction only gains exact-zero terms
         q = jnp.where(dim_mask(q.shape, 1, d, dp, 0), q, 0.0)
-    kb = k_ref[0, 0]  # [bs, dp] packed bits / [bs, d/32*33] payload
-    vb = v_ref[0, 0]
-    if not mx and dp != d:
-        kb = jnp.where(dim_mask(kb.shape, 1, d, dp, 0), kb, 0)
-        vb = jnp.where(dim_mask(vb.shape, 1, d, dp, 0), vb, 0)
-    if S % bs:
-        # padded V rows -> bits/payload 0 -> decode 0.0 (their weight is 0
-        # below, but 0 * garbage-NaN would still poison the accumulator)
-        vb = jnp.where(dim_mask(vb.shape, 0, S, bs, s), vb, 0)
-    k = decode(kb)  # [bs, dp] (block-scaled: [bs, d], zero-padded below)
-    v = decode(vb)
-    if mx and dp != d:
-        # the payload tile is exactly d wide in element units; re-pad the
-        # decoded K/V to the lane-aligned dp with exact zeros to match q
-        pad = [(0, 0), (0, dp - d)]
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
+    kt = [r[0, 0] for r in k_refs]  # [bs, dp] packed bits (+ scale bytes)
+    vt = [r[0, 0] for r in v_refs]
+    if mx:
+        # a garbage scale byte may decode to NaN: mask after the decode
+        k, v = decode(*kt), decode(*vt)
+        if dp != d:
+            k = jnp.where(dim_mask(k.shape, 1, d, dp, 0), k, 0.0)
+            v = jnp.where(dim_mask(v.shape, 1, d, dp, 0), v, 0.0)
+        if S % bs:
+            v = jnp.where(dim_mask(v.shape, 0, S, bs, s), v, 0.0)
+    else:
+        # padded bits -> 0 -> decode 0.0
+        kb, vb = kt[0], vt[0]
+        if dp != d:
+            kb = jnp.where(dim_mask(kb.shape, 1, d, dp, 0), kb, 0)
+            vb = jnp.where(dim_mask(vb.shape, 1, d, dp, 0), vb, 0)
+        if S % bs:
+            # padded V rows (their weight is 0 below, but 0 * garbage-NaN
+            # would still poison the accumulator)
+            vb = jnp.where(dim_mask(vb.shape, 0, S, bs, s), vb, 0)
+        k, v = decode(kb), decode(vb)
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -161,39 +168,41 @@ def takum_decode_attention(
     out_fmt, out_impl = resolve_out_fmt(out_fmt, encode_impl)
     out_mx = out_fmt is not None and wire_format(out_fmt).is_block_scaled
     B, H, d = q.shape
-    _, Hkv, S, dk = k_bits.shape
+    if wf.is_block_scaled:
+        # KV tiles enter split into (element bits, per-element scale bytes):
+        # XLA takes the container apart, since Mosaic cannot split lanes
+        # into 33-byte groups
+        if k_bits.shape[-1] != blockscale.payload_len(d) or d % blockscale.BLOCK:
+            raise ValueError(
+                f"block-scaled KV cache needs a 32-multiple head dim and a "
+                f"{blockscale.payload_len(d)}-byte payload, got d={d}, "
+                f"payload {k_bits.shape[-1]}"
+            )
+        ks = list(blockscale.split_payload(k_bits))
+        vs = list(blockscale.split_payload(v_bits))
+    else:
+        ks, vs = [k_bits], [v_bits]
+    _, Hkv, S, dk = ks[0].shape
     assert H % Hkv == 0
     g = H // Hkv
-    if wf.is_block_scaled:
-        # KV tiles are interleaved payloads: the scale bytes ride in the
-        # same VMEM block as their 32 element bytes (blocked along d)
-        if d % blockscale.BLOCK:
-            raise ValueError(
-                f"block-scaled KV cache needs a 32-multiple head dim, got {d}"
-            )
-        assert dk == blockscale.payload_len(d), (d, dk)
-    else:
-        assert dk == d, (d, dk)
+    assert dk == d, (d, dk)
     if out_mx and d % blockscale.BLOCK:
         raise ValueError(
             f"block-scaled out_fmt needs a 32-multiple head dim, got {d}"
         )
-    bs = choose_block(S, block_s, _SUBLANE)
+    bs = choose_block(S, block_s, sublane_align(ks[0].dtype))
     scale = float(d) ** -0.5  # true head dim: padding adds exact-zero terms
 
     qg = q.reshape(B, Hkv, g, d)
     dp, gp = round_up(d, _LANE), round_up(g, _SUBLANE)
-    dkv = dk if wf.is_block_scaled else dp
 
     grid = (B, Hkv, pl.cdiv(S, bs))
     # blocks are tile-aligned covers of (g, d); edge lanes are masked inside
     # the kernel and the packed KV cache streams through uncopied
-    in_specs = [
-        pl.BlockSpec((1, 1, gp, dp), lambda b, h, s: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, dkv), lambda b, h, s: (b, h, s, 0)),
-        pl.BlockSpec((1, 1, bs, dkv), lambda b, h, s: (b, h, s, 0)),
-    ]
-    args = [qg, k_bits, v_bits]
+    kv_spec = pl.BlockSpec((1, 1, bs, dp), lambda b, h, s: (b, h, s, 0))
+    in_specs = [pl.BlockSpec((1, 1, gp, dp), lambda b, h, s: (b, h, 0, 0))]
+    in_specs += [kv_spec] * (len(ks) + len(vs))
+    args = [qg, *ks, *vs]
     enc_tabs = encode_epilogue_operands(out_fmt, out_impl)
     for t in reversed(enc_tabs):
         in_specs.insert(0, pl.BlockSpec(t.shape, lambda b, h, s: (0, 0)))

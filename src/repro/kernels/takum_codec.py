@@ -14,10 +14,14 @@ takum16 scheme) feeding the VPU — selectable per call via
 
 Block-scaled formats move *interleaved payloads*: 33 uint8 bytes per
 32-element block (scale byte + element bytes, :mod:`repro.quant.blockscale`),
-so the payload axis is 33/32 the element axis.  Tiles stay block-aligned
-(column blocks are 128-multiples, blocks are 32 wide) and the impl knob
-selects the *element* codec inside the container; the E8M0 scale ride-along
-is the same few integer ops either way.  The element axis must be a
+so the payload axis is 33/32 the element axis.  Mosaic cannot split a
+tile's 128 lanes into 33-byte groups, so XLA takes the container apart
+around the kernel: decode gets element bits plus one scale byte per element
+(:func:`~repro.quant.blockscale.split_payload`), encode gets the tile plus
+its per-element scale bytes and returns element bits that XLA interleaves
+with the scales.  The impl knob selects the *element* codec inside the
+container; the E8M0 scale multiply is the same few ops either way.  The
+element axis must be a
 multiple of 32 — callers that own the logical shape pad (QTensor, the
 collectives); ``kernels.ops`` falls back to the jnp reference and raises
 the same alignment error there.
@@ -38,44 +42,37 @@ from jax.experimental import pallas as pl
 
 from repro.core.formats import wire_format
 from repro.quant import blockscale
-from .common import choose_block, interpret_default
+from .common import choose_block, interpret_default, sublane_align
 from .lut import (
     decode_table_operand,
-    encode_epilogue,
     encode_table_operands,
     resolve_impl,
     wire_decode_fn,
+    wire_encode_fn,
 )
 
 
 def _decode_kernel(fmt, impl, *refs):
-    if impl == "lut":
-        tab_ref, b_ref, o_ref = refs
-        decode = wire_decode_fn(fmt, impl, tab_ref)
-    else:
-        b_ref, o_ref = refs
-        decode = wire_decode_fn(fmt, impl)
-    o_ref[...] = decode(b_ref[...])
+    # the decode table leads for the LUT impl; block-scaled formats take
+    # (bits, per-element scale bytes) tiles
+    tab_ref = refs[0] if impl == "lut" else None
+    in_refs, o_ref = refs[int(impl == "lut") : -1], refs[-1]
+    decode = wire_decode_fn(fmt, impl, tab_ref)
+    o_ref[...] = decode(*(r[...] for r in in_refs))
 
 
-def _encode_kernel(fmt, impl, *refs):
-    # table operands lead: (meta, thr) 8-bit / (meta, sub) takum16; the
-    # encode closure is the shared fused-epilogue tail (lut.encode_epilogue),
-    # which also covers the block-scaled payload assembly
-    tabs, (x_ref, o_ref) = refs[:-2], refs[-2:]
-    enc = encode_epilogue(fmt, impl, tabs)
-    o_ref[...] = enc(x_ref[...]).astype(o_ref.dtype)
+def _encode_kernel(fmt, impl, ntab, *refs):
+    # table operands lead: (meta, thr) 8-bit / (meta, sub) takum16;
+    # block-scaled formats take (x, per-element scale bytes) tiles
+    tabs, in_refs, o_ref = refs[:ntab], refs[ntab:-1], refs[-1]
+    enc = wire_encode_fn(fmt, impl, tabs)
+    o_ref[...] = enc(*(r[...] for r in in_refs)).astype(o_ref.dtype)
 
 
-def _blocks(R, C, block_rows, block_cols):
-    br = choose_block(R, block_rows, 8)
+def _blocks(R, C, block_rows, block_cols, packed_dtype):
+    br = choose_block(R, block_rows, sublane_align(packed_dtype))
     bc = choose_block(C, block_cols, 128)
     return br, bc, (pl.cdiv(R, br), pl.cdiv(C, bc))
-
-
-#: element-tile width -> payload-tile width (tiles are 32-aligned, so the
-#: shared helper's pad-to-block is a no-op here)
-_payload_cols = blockscale.payload_len
 
 
 @functools.partial(
@@ -96,12 +93,12 @@ def takum_decode_2d(
     wf = wire_format(fmt)
     name = wf.name
     impl = resolve_impl(decode_impl, name)
-    R, L = bits.shape
-    C = blockscale.elems_len(L) if wf.is_block_scaled else L
-    br, bc, grid = _blocks(R, C, block_rows, block_cols)
-    in_bc = _payload_cols(bc) if wf.is_block_scaled else bc
-    in_specs = [pl.BlockSpec((br, in_bc), lambda i, j: (i, j))]
-    args = [bits]
+    # block-scaled payloads enter the kernel split (XLA takes the container
+    # apart: Mosaic cannot split lanes into 33-byte groups)
+    args = list(blockscale.split_payload(bits)) if wf.is_block_scaled else [bits]
+    R, C = args[0].shape
+    br, bc, grid = _blocks(R, C, block_rows, block_cols, args[0].dtype)
+    in_specs = [pl.BlockSpec((br, bc), lambda i, j: (i, j)) for _ in args]
     if impl == "lut":
         tab = decode_table_operand(name)
         in_specs.insert(0, pl.BlockSpec(tab.shape, lambda i, j: (0, 0)))
@@ -130,28 +127,29 @@ def takum_encode_2d(
     wf = wire_format(fmt)
     impl = resolve_impl(encode_impl, wf.name, op="encode")
     R, C = x.shape
-    if wf.is_block_scaled and C % blockscale.BLOCK:
-        raise ValueError(
-            f"block-scaled encode needs a 32-multiple column count, got {C}"
-        )
-    br, bc, grid = _blocks(R, C, block_rows, block_cols)
-    in_specs = [pl.BlockSpec((br, bc), lambda i, j: (i, j))]
     args = [x]
-    if impl == "lut":
-        tabs = encode_table_operands(wf.name)
-        in_specs = [
-            pl.BlockSpec(t.shape, lambda i, j: (0, 0)) for t in tabs
-        ] + in_specs
-        args = list(tabs) + args
     if wf.is_block_scaled:
-        out_bc, out_cols = _payload_cols(bc), _payload_cols(C)
+        if C % blockscale.BLOCK:
+            raise ValueError(
+                f"block-scaled encode needs a 32-multiple column count, got {C}"
+            )
+        # the per-block scale bytes come from XLA (a segmented absmax), the
+        # element encode runs in the kernel, and XLA interleaves the payload
+        scales = blockscale.block_scale_bytes(x, wf)
+        args.append(blockscale.expand_scales(scales))
+        out_dtype = wf.elem.storage
     else:
-        out_bc, out_cols = bc, C
-    return pl.pallas_call(
-        functools.partial(_encode_kernel, wf.name, impl),
+        out_dtype = wf.storage
+    br, bc, grid = _blocks(R, C, block_rows, block_cols, out_dtype)
+    in_specs = [pl.BlockSpec((br, bc), lambda i, j: (i, j)) for _ in args]
+    tabs = encode_table_operands(wf.name) if impl == "lut" else ()
+    in_specs = [pl.BlockSpec(t.shape, lambda i, j: (0, 0)) for t in tabs] + in_specs
+    out = pl.pallas_call(
+        functools.partial(_encode_kernel, wf.name, impl, len(tabs)),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, out_bc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, out_cols), wf.storage),
+        out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         interpret=interpret,
-    )(*args)
+    )(*tabs, *args)
+    return blockscale.pack_payload(scales, out) if wf.is_block_scaled else out

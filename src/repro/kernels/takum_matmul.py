@@ -42,7 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import wire_format
 from repro.quant import blockscale
-from .common import choose_block, dim_mask, interpret_default
+from .common import choose_block, dim_mask, interpret_default, sublane_align
 from .lut import (
     decode_table_operand,
     encode_epilogue,
@@ -55,38 +55,38 @@ from .lut import (
 
 def _mm_kernel(fmt, impl, dual, K, bk, out_fmt, out_impl, nenc, *refs):
     ndec = 1 if impl == "lut" else 0
-    enc_tabs = refs[ndec : ndec + nenc]
-    x_ref, w_ref, o_ref, acc_ref = refs[ndec + nenc :]
-    decode = wire_decode_fn(fmt, impl, refs[0] if impl == "lut" else None)
     mx = wire_format(fmt).is_block_scaled
+    nw = 2 if mx else 1  # block-scaled operands come as (bits, scale bytes)
+    nx = nw if dual else 1
+    enc_tabs = refs[ndec : ndec + nenc]
+    opnds = refs[ndec + nenc :]
+    x_refs, w_refs = opnds[:nx], opnds[nx : nx + nw]
+    o_ref, acc_ref = opnds[nx + nw :]
+    decode = wire_decode_fn(fmt, impl, refs[0] if impl == "lut" else None)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kid = pl.program_id(2)
-    wb = w_ref[...]
-    if K % bk:
-        # w's K axis is raw rows even for block-scaled formats (blocking is
-        # along N); masking payload rows to 0 decodes to exact zeros
-        wb = jnp.where(dim_mask(wb.shape, 0, K, bk, kid), wb, 0)
-    w = decode(wb)  # VMEM dequant: uint/payload -> f32
 
+    def packed(tile_refs, axis):
+        # VMEM dequant of a packed tile with its K-padding zeroed: bits are
+        # masked to 0 (decodes to 0.0); block-scaled tiles are masked after
+        # the decode, since a garbage scale byte may decode to NaN
+        tiles = [r[...] for r in tile_refs]
+        if K % bk and not mx:
+            tiles[0] = jnp.where(dim_mask(tiles[0].shape, axis, K, bk, kid), tiles[0], 0)
+        v = decode(*tiles)
+        if K % bk and mx:
+            v = jnp.where(dim_mask(v.shape, axis, K, bk, kid), v, 0.0)
+        return v
+
+    w = packed(w_refs, 0)
     if dual:
-        xb = x_ref[...]
-        if mx:
-            # x's K axis *is* the blocked payload axis: decode first, mask
-            # the decoded elements (garbage edge blocks may decode NaN —
-            # the element-unit mask replaces them with exact zeros)
-            x = decode(xb)
-            if K % bk:
-                x = jnp.where(dim_mask(x.shape, 1, K, bk, kid), x, 0.0)
-        else:
-            if K % bk:
-                xb = jnp.where(dim_mask(xb.shape, 1, K, bk, kid), xb, 0)
-            x = decode(xb)
+        x = packed(x_refs, 1)
     else:
-        x = x_ref[...]
+        x = x_refs[0][...]
         if K % bk:
             x = jnp.where(dim_mask(x.shape, 1, K, bk, kid), x, 0)
         x = x.astype(jnp.float32)
@@ -111,27 +111,26 @@ _pc = blockscale.payload_len  # element-tile width -> payload-tile width
 def _call(fmt, impl, dual, x, w, out_dtype, out_fmt, out_impl, bm, bn, bk, interpret):
     mx = wire_format(fmt).is_block_scaled
     out_mx = out_fmt is not None and wire_format(out_fmt).is_block_scaled
-    if dual and mx:
-        # x is an interleaved payload blocked along its last axis (= K)
-        M, K = x.shape[0], blockscale.elems_len(x.shape[1])
-    else:
-        M, K = x.shape
-    # w is blocked along its last axis (= N); its K axis is raw rows
-    K2, N = w.shape[0], (blockscale.elems_len(w.shape[1]) if mx else w.shape[1])
+    # block-scaled payloads enter the kernel split into (element bits,
+    # per-element scale bytes): XLA takes the container apart, since Mosaic
+    # cannot split lanes into 33-byte groups.  w is blocked along N, a dual
+    # x along K — after the split both are plain [rows, elems] operands
+    ws = list(blockscale.split_payload(w)) if mx else [w]
+    xs = list(blockscale.split_payload(x)) if dual and mx else [x]
+    M, K = xs[0].shape
+    K2, N = ws[0].shape
     assert K == K2, (x.shape, w.shape)
     if out_mx and N % blockscale.BLOCK:
         raise ValueError(
             f"block-scaled out_fmt needs a 32-multiple N, got {N}"
         )
-    bm = choose_block(M, bm, 8)
+    bm = choose_block(M, bm, sublane_align(xs[0].dtype))
     bn = choose_block(N, bn, 128)
     bk = choose_block(K, bk, 128)
     grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk))
-    in_specs = [
-        pl.BlockSpec((bm, _pc(bk) if dual and mx else bk), lambda i, j, k: (i, k)),
-        pl.BlockSpec((bk, _pc(bn) if mx else bn), lambda i, j, k: (k, j)),
-    ]
-    args = [x, w]
+    in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)) for _ in xs]
+    in_specs += [pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)) for _ in ws]
+    args = xs + ws
     enc_tabs = encode_epilogue_operands(out_fmt, out_impl)
     for t in reversed(enc_tabs):
         in_specs.insert(0, pl.BlockSpec(t.shape, lambda i, j, k: (0, 0)))

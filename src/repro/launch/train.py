@@ -4,27 +4,26 @@
         --steps 200 --batch 8 --seq 256 --policy takum
 
 Uses the real substrate: synthetic-Markov data pipeline, AdamW (optionally
-takum-quantised moments), checkpoint/restart, metrics CSV.  On a multi-chip
-deployment the same step function runs under the production mesh via
-``--mesh``; on this CPU container it runs single-device (the dry-run covers
-the distributed lowering).
+takum-quantised moments), checkpoint/restart, metrics CSV.  ``--mesh``
+runs the same step function on a data x model or pod x data x model mesh;
+the default ``1x1`` is one device.  :func:`setup` builds the step and
+:func:`run` the loop around it; ``chip_smoke.py`` calls both.
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import functools
 import json
-import os
 import time
 
 import jax
-import jax.numpy as jnp
 
 from repro import configs
 from repro.data import SyntheticLM
 from repro.dist import sharding as shd
 from repro.dist import step as dstep
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import parse_mesh
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
@@ -52,7 +51,79 @@ def build(arch: str, *, smoke: bool, policy: str, seq: int, batch: int):
     return cfg, pipe
 
 
+def setup(cfg, pipe, *, lr: float = 3e-4, mesh: str = "1x1"):
+    """The pieces of a training run of ``cfg`` on ``pipe`` through
+    ``dist.step``: ``(step_fn, batch_fn, init_state, state_sharding)``.
+
+    ``mesh`` is a :func:`~repro.launch.mesh.parse_mesh` spec.  ``step_fn``
+    is the jitted step (train state donated), ``batch_fn(s)`` the placed
+    batch of step ``s``, ``init_state()`` the initial state, placed with
+    ``state_sharding`` (the NamedSharding tree of the state, None on a
+    single device).
+    """
+    m = parse_mesh(mesh)
+    base_step = dstep.make_train_step(cfg, m, lr=lr)
+    sharded = any(v > 1 for v in m.shape.values())
+
+    def make_batch(s):
+        b = pipe.batch(s)
+        if cfg.family == "vlm":
+            b["media"] = pipe.media_stub(s, cfg.num_media_tokens, cfg.media_d)
+        return b
+
+    if sharded:
+        sspec = shd.named(m, dstep.train_state_specs(cfg, m))
+        bspec = shd.named(
+            m, shd.batch_specs(cfg, m, kind="train", batch=pipe.global_batch)
+        )
+        step_fn = jax.jit(base_step, in_shardings=(sspec, bspec),
+                          out_shardings=(sspec, None), donate_argnums=(0,))
+        batch_fn = lambda s: jax.device_put(make_batch(s), bspec)
+        print(f"mesh={dict(m.shape)} (dist.step routing)")
+    else:
+        sspec = None
+        step_fn = jax.jit(base_step, donate_argnums=(0,))
+        batch_fn = make_batch
+
+    # one compiled program, placed where the step wants it: op-by-op, the
+    # init of an 8-layer hymba_1_5b took 53 s on a TPU v5e
+    @functools.partial(jax.jit, out_shardings=sspec)
+    def init_state():
+        params = T.init_params(cfg, jax.random.PRNGKey(0))
+        opt = adamw_init(params, fmt=cfg.quant.opt_state)
+        return dstep.TrainState(params=params, opt=opt, rng=jax.random.PRNGKey(1))
+
+    return step_fn, batch_fn, init_state, sspec
+
+
+def run(cfg, pipe, *, steps: int, lr: float = 3e-4, mesh: str = "1x1",
+        ckpt_dir: str, ckpt_every: int, log_every: int = 10):
+    """Train ``cfg`` on ``pipe`` for ``steps`` steps (see :func:`setup`).
+
+    Checkpoints go to ``ckpt_dir`` every ``ckpt_every`` steps and at the
+    last step, in ``cfg.quant.checkpoint`` format; a checkpoint already in
+    ``ckpt_dir`` is resumed from.  Returns ``(loop, state)``: the finished
+    :class:`~repro.train.TrainLoop` (its ``metrics_history`` holds one entry
+    every ``log_every`` steps) and the final train state.
+    """
+    step_fn, batch_fn, init_state, sspec = setup(cfg, pipe, lr=lr, mesh=mesh)
+    loop = TrainLoop(
+        TrainLoopConfig(
+            total_steps=steps, ckpt_every=ckpt_every,
+            ckpt_dir=ckpt_dir, ckpt_fmt=cfg.quant.checkpoint,
+            log_every=log_every,
+        ),
+        step_fn,
+        batch_fn,
+        init_state,
+        state_sharding=sspec,
+    )
+    state = loop.run()
+    return loop, state
+
+
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lm_100m")
     ap.add_argument("--smoke", action="store_true")
@@ -74,48 +145,9 @@ def main():
                       seq=args.seq, batch=args.batch)
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M policy={args.policy}")
 
-    mesh = parse_mesh(args.mesh)
-    base_step = dstep.make_train_step(cfg, mesh, lr=args.lr)
-    sharded = any(v > 1 for v in mesh.shape.values())
-
-    def make_batch(s):
-        b = pipe.batch(s)
-        if cfg.family == "vlm":
-            b["media"] = pipe.media_stub(s, cfg.num_media_tokens, cfg.media_d)
-        return b
-
-    if sharded:
-        sspec = shd.named(mesh, dstep.train_state_specs(cfg, mesh))
-        bspec = shd.named(
-            mesh, shd.batch_specs(cfg, mesh, kind="train", batch=args.batch)
-        )
-        step_fn = jax.jit(base_step, in_shardings=(sspec, bspec),
-                          out_shardings=(sspec, None), donate_argnums=(0,))
-        batch_fn = lambda s: jax.device_put(make_batch(s), bspec)
-        print(f"mesh={dict(mesh.shape)} (dist.step routing)")
-    else:
-        sspec = None
-        step_fn = jax.jit(base_step, donate_argnums=(0,))
-        batch_fn = make_batch
-
-    def init_state():
-        params = T.init_params(cfg, jax.random.PRNGKey(0))
-        opt = adamw_init(params, fmt=cfg.quant.opt_state)
-        return dstep.TrainState(params=params, opt=opt, rng=jax.random.PRNGKey(1))
-
-    loop = TrainLoop(
-        TrainLoopConfig(
-            total_steps=args.steps, ckpt_every=args.ckpt_every,
-            ckpt_dir=args.ckpt_dir, ckpt_fmt=cfg.quant.checkpoint,
-            log_every=10,
-        ),
-        step_fn,
-        batch_fn,
-        init_state,
-        state_sharding=sspec,
-    )
     t0 = time.time()
-    loop.run()
+    loop, _ = run(cfg, pipe, steps=args.steps, lr=args.lr, mesh=args.mesh,
+                  ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every)
     dt = time.time() - t0
     hist = loop.metrics_history
     print(f"done {args.steps} steps in {dt:.1f}s")

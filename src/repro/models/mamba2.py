@@ -91,9 +91,12 @@ def mamba_forward(pr: MambaParams, u, *, N: int, hd: int, chunk: int, return_sta
     cum = jnp.cumsum(adt, axis=2)  # within-chunk cumulative log-decay
 
     # intra-chunk ("diagonal block"): y_i += sum_{j<=i} C_i.B_j exp(cum_i-cum_j) dt_j x_j
-    decay = jnp.exp(cum[:, :, :, None, :] - cum[:, :, None, :, :])  # [B,nc,Qi,Qj,nh]
+    # masked before the exp: above the diagonal cum_i - cum_j > 0 grows with
+    # the decay rate and overflows to inf, and a where() after the exp then
+    # back-propagates inf * 0 = NaN
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(tri[None, None, :, :, None], decay, 0.0)
+    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [B,nc,Qi,Qj,nh]
+    decay = jnp.exp(jnp.where(tri[None, None, :, :, None], seg, -jnp.inf))
     scores = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)  # [B,nc,Qi,Qj]
     gate = scores[..., None] * decay * dtc[:, :, None, :, :]  # [B,nc,Qi,Qj,nh]
     y_intra = jnp.einsum("bcijh,bcjhd->bcihd", gate, xh)

@@ -160,35 +160,67 @@ def elem_cap(fmt) -> float:
     return float(np.max(finite))
 
 
-def block_quantize(x, fmt, *, elem_encode=None):
-    """f32 [..., n] (n % 32 == 0) -> (scales [..., n/32] uint8, bits [..., n]).
-
-    ``elem_encode`` overrides the element codec (the kernels pass their
-    impl-specific LUT/bits encoder).  The scaled-binade cap is applied
-    *before* the element encode, so any exact RNE encoder of the element
-    format is valid here — clipped values never overflow, which is what
-    makes the OFP8 field packers and the takum encode LUTs interchangeable
-    in the kernel epilogues.
-    """
+def block_scale_bytes(x, fmt):
+    """f32 [..., n] (n % 32 == 0) -> per-block E8M0 scale bytes [..., n/32]."""
     wf = _bs(fmt)
     n = x.shape[-1]
     if n % BLOCK:
         raise ValueError(f"block-scaled last axis must be a multiple of {BLOCK}, got {n}")
     xb = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // BLOCK, BLOCK))
     amax = jnp.max(jnp.abs(xb), axis=-1)  # NaN/Inf propagate -> NaN-scale block
-    sb = scale_bytes(amax, wf.elem_emax)
-    # divide by the scale as an exact power-of-two multiply; 127 - byte in
-    # [-127, 126] needs the two-step split (single _pow2_f32 clips at -126)
-    k = E8M0_BIAS - sb.astype(jnp.int32)
+    return scale_bytes(amax, wf.elem_emax)
+
+
+def expand_scales(scales):
+    """Per-block scale bytes [..., nb] -> one byte per element [..., nb*32]."""
+    return jnp.repeat(scales, BLOCK, axis=-1)
+
+
+def quantize_elems(x, sb, fmt, *, elem_encode=None):
+    """The element half of the MX encode, element-wise: f32 ``x`` and its
+    block's scale byte ``sb`` (broadcastable) -> element bits (uint32).
+
+    Divides by the scale as an exact power-of-two multiply, applies the
+    scaled-binade cap *before* the element encode — so any exact RNE encoder
+    of the element format is valid here (clipped values never overflow),
+    which is what makes the OFP8 field packers and the takum encode LUTs
+    interchangeable in the kernels — and zeroes NaN-scale blocks.
+    """
+    wf = _bs(fmt)
+    sbi = sb.astype(jnp.int32)
+    # 127 - byte in [-127, 126] needs the two-step split (single _pow2_f32
+    # clips at -126)
+    k = E8M0_BIAS - sbi
     ka = jnp.clip(k, -126, 127)
-    xs = xb * _pow2_f32(ka)[..., None] * _pow2_f32(k - ka)[..., None]
+    xs = x.astype(jnp.float32) * _pow2_f32(ka) * _pow2_f32(k - ka)
     cap = jnp.float32(elem_cap(wf))
     xs = jnp.clip(xs, -cap, cap)  # the saturating MX conversion (module doc)
     enc = elem_encode if elem_encode is not None else wf.elem.encode_jnp
-    bits = enc(xs)
+    bits = enc(xs).astype(_U)
     # NaN-scale blocks carry zero element bits: decode is NaN regardless
     # (OCP block-NaN), and zeroing keeps the payload deterministic
-    bits = jnp.where(sb[..., None] == E8M0_NAN, 0, bits.astype(_U))
+    return jnp.where(sbi == E8M0_NAN, _U(0), bits)
+
+
+def dequantize_elems(bits, sb, fmt, *, elem_decode=None):
+    """The MX decode, element-wise: element bits and their block's scale
+    byte (broadcastable) -> f32 ``scale * element``."""
+    wf = _bs(fmt)
+    dec = elem_decode if elem_decode is not None else wf.elem.decode_jnp
+    return (dec(bits) * e8m0_decode(sb)).astype(jnp.float32)
+
+
+def block_quantize(x, fmt, *, elem_encode=None):
+    """f32 [..., n] (n % 32 == 0) -> (scales [..., n/32] uint8, bits [..., n]).
+
+    ``elem_encode`` overrides the element codec (the kernels pass their
+    impl-specific LUT/bits encoder); see :func:`quantize_elems`.
+    """
+    wf = _bs(fmt)
+    n = x.shape[-1]
+    sb = block_scale_bytes(x, wf)
+    xb = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // BLOCK, BLOCK))
+    bits = quantize_elems(xb, sb[..., None], wf, elem_encode=elem_encode)
     return sb, bits.reshape(x.shape).astype(wf.elem.storage)
 
 
@@ -198,12 +230,10 @@ def block_dequantize(scales, bits, fmt, *, elem_decode=None):
     ``value = scale * element`` in f32 (OCP decode semantics: overflow past
     f32 goes to Inf, underflow flushes); NaN-scale blocks are all-NaN.
     """
-    wf = _bs(fmt)
     n = bits.shape[-1]
-    dec = elem_decode if elem_decode is not None else wf.elem.decode_jnp
-    vals = dec(bits).reshape(bits.shape[:-1] + (n // BLOCK, BLOCK))
-    scale = e8m0_decode(scales)
-    return (vals * scale[..., None]).reshape(bits.shape[:-1] + (n,)).astype(jnp.float32)
+    bb = bits.reshape(bits.shape[:-1] + (n // BLOCK, BLOCK))
+    vals = dequantize_elems(bb, scales[..., None], fmt, elem_decode=elem_decode)
+    return vals.reshape(bits.shape[:-1] + (n,))
 
 
 def pack_payload(scales, bits):
@@ -229,6 +259,15 @@ def unpack_payload(payload):
     nb = elems_len(payload.shape[-1]) // BLOCK
     grp = payload.reshape(payload.shape[:-1] + (nb, GROUP))
     return grp[..., 0], grp[..., 1:].reshape(payload.shape[:-1] + (nb * BLOCK,))
+
+
+def split_payload(payload):
+    """payload [..., nb*33] -> (element bits [..., nb*32], per-element scale
+    bytes [..., nb*32]): the form the Pallas kernels take a block-scaled
+    operand in.  Mosaic cannot split a tile's lanes into 33-byte groups, so
+    the container is taken apart by XLA around the kernel call."""
+    scales, bits = unpack_payload(payload)
+    return bits, expand_scales(scales)
 
 
 def encode_payload(x, fmt, *, elem_encode=None):

@@ -138,3 +138,53 @@ def test_cells_grid():
         "musicgen_large", "kimi_k2_1t_a32b", "dbrx_132b", "gemma2_2b",
         "llama3_8b", "llama3_2_3b", "granite_34b", "llama3_2_vision_90b",
     }
+
+
+@pytest.mark.parametrize("arch", ["hymba_1_5b", "llama3_8b"])
+def test_dist_serve_steps_match_forward(arch):
+    """``dist.step``'s prefill then N serve steps, over packed weights, must
+    reproduce the full forward's logits at every position.  The prefill is
+    given the decode budget (``cache_len``); without it the cache is exactly
+    the prompt long and every decode write clamps onto the last prompt slot.
+    """
+    from repro.dist import step as dstep
+    from repro.launch.mesh import parse_mesh
+
+    cfg = configs.get_smoke(arch).with_(
+        quant=QuantPolicy(weights="t16", kv_cache="f32", activations="f32")
+    )
+    mesh = parse_mesh("1x1")
+    qp = dstep.quantize_params(cfg, T.init_params(cfg, jax.random.PRNGKey(4)))
+    B, S, S0 = 2, 24, 12
+    tokens = _batch(cfg, B=B, S=S, seed=5)["tokens"]
+    want, _, _ = T.forward(cfg, dstep.dequantize_params(qp), tokens)
+
+    prefill = jax.jit(dstep.make_prefill_step(cfg, mesh, cache_len=S))
+    serve = jax.jit(dstep.make_serve_step(cfg, mesh), donate_argnums=(2,))
+    last, cache = prefill(qp, {"tokens": tokens[:, :S0]})
+    got = [last]
+    for t in range(S0, S - 1):
+        lg, cache = serve(qp, {"token": tokens[:, t]}, cache)
+        got.append(lg)
+    got = np.stack([np.asarray(g) for g in got], axis=1)
+    np.testing.assert_allclose(got, np.asarray(want[:, S0 - 1 : S - 1]), rtol=1e-3, atol=1e-3)
+    assert int(cache.pos) == S - 1 and cache.k.shape[2] == S
+
+
+def test_mamba_grads_finite_under_steep_decay():
+    """A chunk whose summed log-decay passes f32's exp range (fast heads,
+    large dt — as deep layers of a full-width model reach) must still give
+    finite gradients: the SSD's upper-triangle decays are masked before
+    their exp, not after it."""
+    from repro.models import mamba2
+
+    d_model, d_in, N, hd, Q = 32, 64, 8, 16, 32
+    pr = mamba2.init_mamba(jax.random.PRNGKey(0), d_model, d_in, N, hd, w=4)
+    pr = pr._replace(dt_bias=jnp.full_like(pr.dt_bias, 5.0))  # dt ~ 5
+    u = jax.random.normal(jax.random.PRNGKey(1), (2, 2 * Q, d_model))
+    loss = lambda p, u: jnp.sum(mamba2.mamba_forward(p, u, N=N, hd=hd, chunk=Q) ** 2)
+    out = mamba2.mamba_forward(pr, u, N=N, hd=hd, chunk=Q)
+    grads = jax.grad(loss, argnums=(0, 1))(pr, u)
+    assert np.isfinite(np.asarray(out)).all()
+    for g in jax.tree.leaves(grads):
+        assert np.isfinite(np.asarray(g)).all()

@@ -35,7 +35,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 """
 
 
@@ -48,7 +48,7 @@ from repro.optim import adamw_init
 from repro.data import SyntheticLM
 
 cfg = configs.get_smoke("llama3_8b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 pipe = SyntheticLM(cfg.vocab_size, 32, 4, seed=5)
 batch = pipe.batch(0)
 
@@ -139,7 +139,8 @@ cfg = configs.get_smoke("llama3_8b").with_(quant=QuantPolicy(
 # embedding gather meets a manual pod axis on tiny model-sharded meshes;
 # the production 2x16x16 mesh compiles this exact path (pod2 dry-run sweep),
 # so the test pins the pod-compression machinery with TP disabled.
-mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 pipe = SyntheticLM(cfg.vocab_size, 32, 4, seed=5)
 batch = pipe.batch(0)
 params = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -166,7 +167,7 @@ from repro import configs
 from repro.launch import dryrun
 for arch, shape in [("llama3_2_3b", "decode_32k"), ("mamba2_780m", "long_500k")]:
     cfg = configs.get_smoke(arch)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 rec = dryrun.run_cell("musicgen_large", "train_4k", multi_pod=False, mesh=mesh)
 ok1 = rec["collectives"]["total_bytes"] > 0 and rec["cost"]["flops"] > 0
 rec2 = dryrun.run_cell("hymba_1_5b", "long_500k", multi_pod=False, mesh=mesh)

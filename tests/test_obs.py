@@ -310,7 +310,6 @@ def test_validate_chrome_trace_rejects_garbage():
 def test_shard_map_multiplicity_counters_hists_spans():
     out = _run(_PRE + """
 from repro.core import telemetry
-from repro.dist._compat import shard_map
 
 mesh = jax.make_mesh((8,), ("x",))
 
@@ -323,8 +322,8 @@ def body(x):
     return y
 
 with telemetry.capture() as ctrs:
-    f = shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                      check_vma=False)
     jax.block_until_ready(jax.jit(f)(jnp.arange(16.0)))
 
 snap = telemetry.snapshot()
@@ -395,6 +394,7 @@ print(json.dumps({{
     "jsonl_kinds": sorted({{l["kind"] for l in lines}}),
     "kernel_calls": snap["counters"].get("kernel.calls.decode.t8", 0.0),
     "wire_hops": snap["counters"].get("wire.hops", 0.0),
+    "grad_leaves": len(jax.tree.leaves(params)),
     "step_calls": snap["counters"].get("step.calls", 0.0),
     "grad_norm_count": snap["hists"]["step.grad_norm"]["count"],
 }}))
@@ -409,6 +409,7 @@ print(json.dumps({{
     assert {"counter", "hist", "span"} <= set(out["jsonl_kinds"]), out
     # online metrics wired through the same capture
     assert out["kernel_calls"] == 1.0  # eager dispatch: multiplicity 1
-    assert out["wire_hops"] == 24.0  # (N-1)=3 hops x 8 devices
+    # one ring per gradient leaf: (N-1)=3 hops x 8 devices each
+    assert out["wire_hops"] == 24.0 * out["grad_leaves"]
     assert out["step_calls"] == 1.0
     assert out["grad_norm_count"] == 1
