@@ -1,12 +1,11 @@
 """Host-side metrics registry for the wire stack: counters, gauges,
-histograms, and trace-time-gated timer spans.
+histograms and host spans.
 
 PR 6 proved the zero-cost-when-idle counter pattern (fault containment,
 DESIGN.md §8); this module generalises it into the online half of the
-``repro.obs`` observability subsystem (DESIGN.md §9).  Everything of
-interest happens *inside* jitted/shard_map regions, so all device-side
-instrumentation is surfaced through ``jax.debug.callback`` into
-process-global stores.
+``repro.obs`` observability subsystem (DESIGN.md §9).  Counters emitted
+*inside* jitted/shard_map regions reach the host through
+``jax.debug.callback`` into process-global stores.
 
 Metric kinds (all keyed by dotted tags — ``wire.hop_bytes``,
 ``kernel.calls.matmul.t8``, ``step.grad_norm``, ...):
@@ -16,15 +15,16 @@ Metric kinds (all keyed by dotted tags — ``wire.hop_bytes``,
 * **histogram** — running count/sum/min/max plus a bounded, deterministic
   stride-decimated sample for quantiles (:func:`emit_hist` /
   :func:`record_hist`).
-* **span** — a named timed interval.  :func:`host_span` measures a host-side
-  region with real wall clock (train-loop steps, bench reps, eager
-  dispatch); :func:`trace_span` instruments *traced* code with a paired
-  begin/end callback whose host arrival times bracket the async device
-  execution ("callback clock": approximate, honest — the callbacks are
-  unordered, so durations are indicative rather than exact, and an end may
-  occasionally pair with a neighbouring execution's begin under overlap).
-  Spans carry a category (``kernel`` / ``collective`` / ``step`` / ...)
-  used by the Chrome-trace export (:mod:`repro.obs.trace_export`).
+* **host span** — :func:`host_span` measures a host-side region with real
+  wall clock (train-loop steps, bench reps) and always enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the region lands in
+  a profiler trace beside the device ops.
+
+Device time has no recorder here.  Traced code names its regions with
+``jax.named_scope`` (``embed``, ``layers``, ``mamba.ssd``, ``optimizer``,
+``kernel.<op>.<fmt>``, ``wire.hop.<fmt>``, ...): pure metadata on the HLO
+ops, present in every run, which a profiler trace of the device attributes
+time to (DESIGN.md §9).
 
 Usage::
 
@@ -32,31 +32,23 @@ Usage::
         fn = jax.jit(step)          # trace INSIDE the capture scope
         fn(...)
     counters["wire.escalations"]    # accumulated across all calls
-    telemetry.spans()               # list of recorded span dicts
+    telemetry.spans()               # list of recorded host span dicts
     telemetry.snapshot()            # everything, export-ready
 
 Two gates keep the cost at zero when nobody is listening:
 
-* every ``emit*``/``trace_span`` is a **trace-time** no-op unless a capture
-  scope is active when the emitting code is *traced* — a jitted function
-  traced outside ``capture()`` carries no callbacks (and no extra ops at
-  all: the zero-op property is asserted on the jaxpr in tests/test_obs.py).
+* every ``emit*`` is a **trace-time** no-op unless a capture scope is
+  active when the emitting code is *traced* — a jitted function traced
+  outside ``capture()`` carries no callbacks (and no extra ops at all: the
+  zero-op property is asserted on the jaxpr in tests/test_obs.py).
   Conversely, one traced inside keeps emitting for its cached lifetime;
   tests that need isolation run in fresh subprocesses.
 * at runtime, values arriving while no capture is active are dropped.
 
 Under shard_map every device emits, so per-device quantities arrive
-``N``-fold: counters sum N devices' values, histograms take N samples per
-logical event, and a ``trace_span`` yields N span records per traced
-execution.  Emit pre-reduced values or document the multiplicity at the tag
-(DESIGN.md §9 lists the rule per tag namespace).
-
-``jax.profiler.TraceAnnotation`` bridging: :func:`annotate_xla` (or
-``capture(annotate_xla=True)``) makes :func:`host_span` also enter a
-profiler ``TraceAnnotation``, so host spans line up with XLA device traces
-when ``jax.profiler`` is active; :func:`trace_span` always wraps the traced
-region in ``jax.named_scope`` (pure metadata — the HLO ops carry the span
-name, which is what the XLA profile groups by).
+``N``-fold: counters sum N devices' values and histograms take N samples
+per logical event.  Emit pre-reduced values or document the multiplicity
+at the tag (DESIGN.md §9 lists the rule per tag namespace).
 """
 
 from __future__ import annotations
@@ -64,35 +56,22 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
-import itertools
 import threading
 import time
 
 import jax
-import jax.numpy as jnp
 
 _LOCK = threading.Lock()
 _COUNTERS: collections.Counter = collections.Counter()
 _GAUGES: dict = {}
 _HISTS: dict = {}
 _SPANS: list = []
-_OPEN: dict = {}  # span id -> deque of (t0, thread) awaiting their end
-_DROPPED_SPANS = 0
 _DEPTH = 0  # capture scopes may nest; any active scope enables recording
-_ANNOTATE_XLA = False
-_SPAN_IDS = itertools.count()
 
 
 def enabled() -> bool:
     """True while at least one :func:`capture` scope is active."""
     return _DEPTH > 0
-
-
-def annotate_xla(flag: bool) -> None:
-    """Bridge host spans into ``jax.profiler.TraceAnnotation`` so they line
-    up with XLA profiles (optional: annotations are cheap but not free)."""
-    global _ANNOTATE_XLA
-    _ANNOTATE_XLA = bool(flag)
 
 
 # ---------------------------------------------------------------------------
@@ -180,30 +159,6 @@ def _record_span(name: str, cat: str, t0: float, t1: float, args=None) -> None:
             })
 
 
-def _span_begin(sid: int, name: str, cat: str, *_dummy) -> None:
-    if _DEPTH > 0:
-        with _LOCK:
-            _OPEN.setdefault(sid, collections.deque()).append(
-                (name, cat, time.perf_counter())
-            )
-
-
-def _span_end(sid: int, *_dep) -> None:
-    global _DROPPED_SPANS
-    if _DEPTH > 0:
-        t1 = time.perf_counter()
-        with _LOCK:
-            q = _OPEN.get(sid)
-            if not q:
-                _DROPPED_SPANS += 1  # end arrived without a live begin
-                return
-            name, cat, t0 = q.popleft()
-            _SPANS.append({
-                "name": name, "cat": cat, "t0": t0, "t1": t1,
-                "tid": threading.get_ident(),
-            })
-
-
 # ---------------------------------------------------------------------------
 # trace-safe emitters (double-gated: trace-time no-op without a capture)
 # ---------------------------------------------------------------------------
@@ -232,73 +187,17 @@ def emit_hist(tag: str, value) -> None:
         )
 
 
-class _SpanHandle:
-    """Yielded by :func:`trace_span`; set ``.dep`` to a (cheap, scalar)
-    value computed from the span's result to give the end callback a data
-    dependency — the runtime then cannot fire it before the result exists."""
-
-    __slots__ = ("dep",)
-
-    def __init__(self):
-        self.dep = None
-
-
-def probe(x):
-    """Cheap scalar data-dependency on ``x`` for span end callbacks: one
-    element, so the host transfer is O(1) regardless of ``x``'s size."""
-    x = jnp.asarray(x)
-    if x.size == 0:
-        return jnp.float32(0)
-    return jax.lax.slice(x.reshape(-1), (0,), (1,))
-
-
-@contextlib.contextmanager
-def trace_span(name: str, cat: str = "trace"):
-    """Timer span around *traced* code (usable inside jit/shard_map and in
-    eager code alike).  Zero added ops unless a capture scope is active at
-    trace time; under a capture, a begin/end callback pair brackets the
-    region ("callback clock" — see module docstring) and the traced ops are
-    wrapped in ``jax.named_scope(name)`` so XLA profiles carry the name.
-
-    Yields a :class:`_SpanHandle`: optionally set ``handle.dep =
-    probe(result)`` so the end callback waits for the result.
-    """
-    if _DEPTH == 0:
-        yield _SpanHandle()
-        return
-    sid = next(_SPAN_IDS)
-    # the dummy operand keeps the callback legal in traces that reject
-    # zero-operand callbacks (eager shard_map bodies in this jax version)
-    jax.debug.callback(
-        functools.partial(_span_begin, sid, name, cat), jnp.uint8(0),
-        ordered=False,
-    )
-    h = _SpanHandle()
-    with jax.named_scope(name):
-        yield h
-    end = functools.partial(_span_end, sid)
-    jax.debug.callback(
-        end, jnp.uint8(0) if h.dep is None else h.dep, ordered=False
-    )
-
-
 @contextlib.contextmanager
 def host_span(name: str, cat: str = "host", **args):
     """Wall-clock span over a host-side region (no tracing involved): the
-    train loop's per-step timing, bench repetitions, export passes.  Gated
-    at runtime only — host code has no trace time — so it is safe (and
+    train loop's per-step timing, bench repetitions, export passes.  The
+    region always enters a ``jax.profiler.TraceAnnotation`` named ``name``
+    (a no-op unless a profiler trace is running), and is recorded only under
+    :func:`capture` — host code has no trace time — so it is safe (and
     free) to leave in place permanently."""
-    if _DEPTH == 0:
-        yield
-        return
-    ann = (
-        jax.profiler.TraceAnnotation(name)
-        if _ANNOTATE_XLA
-        else contextlib.nullcontext()
-    )
     t0 = time.perf_counter()
     try:
-        with ann:
+        with jax.profiler.TraceAnnotation(name):
             yield
     finally:
         _record_span(name, cat, t0, time.perf_counter(), args or None)
@@ -330,10 +229,6 @@ def spans() -> list:
         return list(_SPANS)
 
 
-def dropped_spans() -> int:
-    return _DROPPED_SPANS
-
-
 def snapshot() -> dict:
     """Everything the exporters consume, in one consistent view."""
     with _LOCK:
@@ -342,30 +237,24 @@ def snapshot() -> dict:
             "gauges": dict(_GAUGES),
             "hists": {tag: h.summary() for tag, h in _HISTS.items()},
             "spans": list(_SPANS),
-            "dropped_spans": _DROPPED_SPANS,
         }
 
 
 def reset() -> None:
-    global _DROPPED_SPANS
     with _LOCK:
         _COUNTERS.clear()
         _GAUGES.clear()
         _HISTS.clear()
         _SPANS.clear()
-        _OPEN.clear()
-        _DROPPED_SPANS = 0
 
 
 @contextlib.contextmanager
-def capture(fresh: bool = True, annotate_xla: bool | None = None):
+def capture(fresh: bool = True):
     """Enable metric recording; yields the live counter store (the
     historical API — gauges/hists/spans are read via :func:`gauges` /
     :func:`hists` / :func:`spans` / :func:`snapshot`).  ``fresh`` resets
     accumulated state on entry of the *outermost* scope only: nested scopes
     share one store and never clear it (asserted in tests/test_obs.py).
-    ``annotate_xla`` optionally flips the TraceAnnotation bridge for the
-    scope's duration.
 
     Exit blocks on :func:`jax.effects_barrier`: the debug callbacks are
     unordered and asynchronous, so without a flush an emission from a
@@ -373,12 +262,9 @@ def capture(fresh: bool = True, annotate_xla: bool | None = None):
     dropped by the runtime gate.  Flushing before the depth decrement makes
     the exited store complete for everything launched inside the scope.
     """
-    global _DEPTH, _ANNOTATE_XLA
+    global _DEPTH
     if fresh and _DEPTH == 0:
         reset()
-    prev_ann = _ANNOTATE_XLA
-    if annotate_xla is not None:
-        _ANNOTATE_XLA = bool(annotate_xla)
     _DEPTH += 1
     try:
         yield _COUNTERS
@@ -387,4 +273,3 @@ def capture(fresh: bool = True, annotate_xla: bool | None = None):
             jax.effects_barrier()
         finally:
             _DEPTH -= 1
-            _ANNOTATE_XLA = prev_ann

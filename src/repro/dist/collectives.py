@@ -152,11 +152,12 @@ def _ring_reduce(wire, own_f32, axis_name, decode, N: int,
     containment is off); each hop message lands on exactly one device, so
     the per-device counts sum to the global count.
 
-    Observability (``wire.*``, DESIGN.md §9; zero ops unless a telemetry
-    capture is active at trace time): per ring call, ``wire.hops`` (N-1)
-    and ``wire.hop_bytes`` (the honest per-device wire traffic, payload
-    bytes x hops), plus one ``wire.hop.<fmt>`` span (cat ``collective``)
-    per hop — per device, so totals carry the ring multiplicity N.
+    Observability (``wire.*``, DESIGN.md §9): each hop runs inside
+    ``jax.named_scope("wire.hop.<fmt>")`` in every run; under a telemetry
+    capture at trace time (zero ops otherwise), per ring call,
+    ``wire.hops`` (N-1) and ``wire.hop_bytes`` (the honest per-device wire
+    traffic, payload bytes x hops) — per device, so totals carry the ring
+    multiplicity N.
     """
     def arm(term):
         if contain_abs is None:
@@ -173,10 +174,9 @@ def _ring_reduce(wire, own_f32, axis_name, decode, N: int,
         telemetry.emit("wire.hops", float(N - 1))
         telemetry.emit("wire.hop_bytes", (N - 1) * msg_bytes)
     for _ in range(N - 1):
-        with telemetry.trace_span(f"wire.hop.{fmt_name}", cat="collective") as sp:
+        with jax.named_scope(f"wire.hop.{fmt_name}"):
             msg = faults.corrupt_hop(jax.lax.ppermute(msg, axis_name, perm), axis_name)
             term, c = arm(decode(msg))  # hop i carries source (p - i) % N
-            sp.dep = telemetry.probe(term)
         contained = contained + c
         terms.append(term)
     stacked = jnp.stack(terms)
@@ -222,7 +222,7 @@ def compressed_psum(x, axis_name, fmt="t8", *, exact_local: bool = True,
         # slice back out (zero padding never perturbs a block's scale)
         xf = blockscale.pad_block(jnp.atleast_1d(xf))
     encode, decode = wire_codec(wf.name, sr_key=sr_key)
-    with telemetry.trace_span(f"wire.ring.{wf.name}", cat="collective") as sp:
+    with jax.named_scope(f"wire.ring.{wf.name}"):
         wire = encode(xf)
         own = xf if exact_local else decode(wire)
         out, _ = _ring_reduce(
@@ -230,7 +230,6 @@ def compressed_psum(x, axis_name, fmt="t8", *, exact_local: bool = True,
         )
         if wf.is_block_scaled:
             out = out[..., :n].reshape(jnp.shape(x))
-        sp.dep = telemetry.probe(out)
     telemetry.emit("wire.calls", jnp.float32(1))
     telemetry.emit(f"wire.rung.{wf.name}", jnp.float32(1))
     return out

@@ -193,15 +193,13 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str = "pipe",
                 out_buf = out_buf.at[m].set(jnp.where(p == nstages - 1, out, 0.0))
             if nstages > 1:
                 telemetry.emit("pipe.ticks", jnp.float32(1))
-                with telemetry.trace_span(f"pipe.hop.{name}",
-                                          cat="collective") as sp:
+                with jax.named_scope(f"pipe.hop.{name}"):
                     if hop_encode is None:
                         recv = plain_hop(out, perm)
                     elif guard is None:
                         recv = coded_hop(out, perm, x_all.dtype)
                     else:
                         recv = guarded_hop(out, perm, x_all.dtype)
-                    sp.dep = telemetry.probe(recv)
         return jax.lax.psum(out_buf, axis)
 
     fn = jax.shard_map(

@@ -58,6 +58,14 @@ def _has_pod(mesh) -> bool:
     return "pod" in mesh.axis_names and mesh.shape["pod"] > 1
 
 
+def _all_finite(leaves):
+    """f32 1.0 when every element of every leaf is finite, else 0.0."""
+    ok = jnp.float32(1)
+    for g in leaves:
+        ok = ok * jnp.isfinite(g).all().astype(jnp.float32)
+    return ok
+
+
 def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                     master_dtype=jnp.float32):
     """Build ``step(state, batch) -> (state, metrics)`` for ``cfg`` on ``mesh``.
@@ -102,9 +110,8 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                 # the evidence: pmean'd into the [0,1] fraction of devices
                 # whose local grads were all-finite (1.0 = clean step)
                 flat, treedef = jax.tree.flatten(grads)
-                grads_ok = jnp.float32(1)
-                for g in flat:
-                    grads_ok = grads_ok * jnp.isfinite(g).all().astype(jnp.float32)
+                with jax.named_scope("grad_stats"):
+                    grads_ok = _all_finite(flat)
                 if data_axes:
                     flat = jax.lax.pmean(flat, data_axes)
 
@@ -164,53 +171,52 @@ def make_train_step(cfg, mesh, *, lr=3e-4, aux_weight: float = 0.01,
                 params, batch
             )
             grads = faults.poison_grads(grads, wire_key)
-            ok = jnp.float32(1)
-            for g in jax.tree.leaves(grads):
-                ok = ok * jnp.isfinite(g).all().astype(jnp.float32)
+            with jax.named_scope("grad_stats"):
+                ok = _all_finite(jax.tree.leaves(grads))
             metrics = {**metrics, "grad_ok": ok}
             return loss, metrics, grads
 
     def step(state: TrainState, batch):
-        with telemetry.trace_span("step.train", cat="step") as sp:
-            rng, sr_key, wire_key = jax.random.split(state.rng, 3)
-            loss, metrics, grads = fwd_bwd(state.params, batch, wire_key)
+        rng, sr_key, wire_key = jax.random.split(state.rng, 3)
+        loss, metrics, grads = fwd_bwd(state.params, batch, wire_key)
+        with jax.named_scope("grad_stats"):
             # global L2 norm of the reduced gradients (after the compressed
             # ring on pod meshes)
             gn = jnp.sqrt(sum(
                 jnp.sum(jnp.square(g.astype(jnp.float32)))
                 for g in jax.tree.leaves(grads)
             ))
-            if telemetry.enabled():
-                # one record per *execution* of this trace (the step runs
-                # outside shard_map, so multiplicity is 1, not n_devices)
-                telemetry.emit("step.calls", jnp.float32(1))
-                tok = batch.get("tokens")
-                if tok is not None:
-                    telemetry.emit("step.tokens", float(tok.shape[0] * tok.shape[1]))
-                telemetry.emit_hist("step.grad_norm", gn)
-            use_sr = cfg.quant.stochastic_rounding and is_takum(cfg.quant.opt_state)
+        if telemetry.enabled():
+            # one record per *execution* of this trace (the step runs
+            # outside shard_map, so multiplicity is 1, not n_devices)
+            telemetry.emit("step.calls", jnp.float32(1))
+            tok = batch.get("tokens")
+            if tok is not None:
+                telemetry.emit("step.tokens", float(tok.shape[0] * tok.shape[1]))
+            telemetry.emit_hist("step.grad_norm", gn)
+        use_sr = cfg.quant.stochastic_rounding and is_takum(cfg.quant.opt_state)
+        with jax.named_scope("optimizer"):
             new_params, new_opt = adamw_update(
                 grads, state.opt, state.params, lr=lr, fmt=cfg.quant.opt_state,
                 key=sr_key if use_sr else None,
             )
-            out = {"loss": loss, "ce": metrics["ce"], "aux": metrics["aux"],
-                   "grad_norm": gn}
-            guard = cfg.quant.guard
-            if guard is not None and guard.skip_nonfinite_update:
-                # GradScaler-style microbatch skip: a step whose raw gradients
-                # were not everywhere finite leaves params AND opt state
-                # untouched (training on contained-to-zero garbage would still
-                # corrupt the Adam moments).  grad_ok is a pmean'd fraction, so
-                # every device takes the same branch.
-                ok = metrics["grad_ok"] >= jnp.float32(0.999)
-                keep = lambda n, o: jnp.where(ok, n, o)
-                params = jax.tree.map(keep, new_params, state.params)
-                opt = jax.tree.map(keep, new_opt, state.opt)
-                telemetry.emit("step.skipped", jnp.float32(1) - ok.astype(jnp.float32))
-                out["grad_ok"] = metrics["grad_ok"]
-            else:
-                params, opt = new_params, new_opt
-            sp.dep = telemetry.probe(loss)
+        out = {"loss": loss, "ce": metrics["ce"], "aux": metrics["aux"],
+               "grad_norm": gn}
+        guard = cfg.quant.guard
+        if guard is not None and guard.skip_nonfinite_update:
+            # GradScaler-style microbatch skip: a step whose raw gradients
+            # were not everywhere finite leaves params AND opt state
+            # untouched (training on contained-to-zero garbage would still
+            # corrupt the Adam moments).  grad_ok is a pmean'd fraction, so
+            # every device takes the same branch.
+            ok = metrics["grad_ok"] >= jnp.float32(0.999)
+            keep = lambda n, o: jnp.where(ok, n, o)
+            params = jax.tree.map(keep, new_params, state.params)
+            opt = jax.tree.map(keep, new_opt, state.opt)
+            telemetry.emit("step.skipped", jnp.float32(1) - ok.astype(jnp.float32))
+            out["grad_ok"] = metrics["grad_ok"]
+        else:
+            params, opt = new_params, new_opt
         return TrainState(params=params, opt=opt, rng=rng), out
 
     return step
@@ -319,7 +325,8 @@ def make_prefill_step(cfg, mesh, *, cache_len: int | None = None):
     """
 
     def step(params, batch):
-        p = dequantize_params(params)
+        with jax.named_scope("serve.weights"):
+            p = dequantize_params(params)
         with actx.use_mesh(mesh):
             return T.prefill(
                 cfg, p, batch["tokens"], batch.get("media"), cache_len=cache_len
@@ -332,7 +339,8 @@ def make_serve_step(cfg, mesh):
     """``step(params, batch, cache) -> (logits, cache)`` single-token decode."""
 
     def step(params, batch, cache):
-        p = dequantize_params(params)
+        with jax.named_scope("serve.weights"):
+            p = dequantize_params(params)
         with actx.use_mesh(mesh):
             return T.decode_step(cfg, p, batch["token"], cache, batch.get("media"))
 

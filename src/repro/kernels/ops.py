@@ -24,6 +24,7 @@ the kernel epilogue and return packed bits instead of f32.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import telemetry
@@ -45,22 +46,22 @@ def _wire_bytes(*arrs) -> float:
 def _observed(op: str, fmt_name: str, call, *wire_arrs, out_is_wire=False):
     """Dispatch-layer observability (DESIGN.md §9, ``kernel.*`` namespace).
 
-    Zero added ops unless a :func:`repro.core.telemetry.capture` scope is
-    active at trace time (asserted on the jaxpr in tests/test_obs.py).
-    Under a capture, every dispatch emits one ``kernel.calls.<op>.<fmt>``
-    counter, charges ``kernel.wire_bytes.<op>`` with the packed bytes it
-    moved (the wire-side input operands, plus the packed output when
-    ``out_is_wire`` — i.e. ``encode`` and the fused ``out_fmt=``
-    producers), and brackets the op in a ``kernel.<op>.<fmt>`` span
-    (category ``kernel``) whose end callback data-depends on the result.
-    Under shard_map the counts arrive once per device (multiplicity N).
+    Every dispatch runs inside ``jax.named_scope("kernel.<op>.<fmt>")``, in
+    every run: metadata on the kernel's HLO ops that a profiler trace of
+    the device attributes time to.  The counters add zero ops unless a
+    :func:`repro.core.telemetry.capture` scope is active at trace time
+    (asserted on the jaxpr in tests/test_obs.py).  Under a capture, every
+    dispatch emits one ``kernel.calls.<op>.<fmt>`` counter and charges
+    ``kernel.wire_bytes.<op>`` with the packed bytes it moved (the
+    wire-side input operands, plus the packed output when ``out_is_wire``
+    — i.e. ``encode`` and the fused ``out_fmt=`` producers).  Under
+    shard_map the counts arrive once per device (multiplicity N).
     """
-    if not telemetry.enabled():
-        return call()
-    telemetry.emit(f"kernel.calls.{op}.{fmt_name}", 1.0)
-    with telemetry.trace_span(f"kernel.{op}.{fmt_name}", cat="kernel") as sp:
+    with jax.named_scope(f"kernel.{op}.{fmt_name}"):
         out = call()
-        sp.dep = telemetry.probe(out)
+    if not telemetry.enabled():
+        return out
+    telemetry.emit(f"kernel.calls.{op}.{fmt_name}", 1.0)
     nbytes = _wire_bytes(*wire_arrs) + (_wire_bytes(out) if out_is_wire else 0.0)
     if nbytes:
         telemetry.emit(f"kernel.wire_bytes.{op}", nbytes)

@@ -27,9 +27,10 @@ def linear(x, w):
 
 
 def rms_norm(x, gamma, eps: float = 1e-5):
-    xf = x.astype(jnp.float32)
-    s = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return ((xf * s) * (1.0 + gamma.astype(jnp.float32))).astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        s = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+        return ((xf * s) * (1.0 + gamma.astype(jnp.float32))).astype(x.dtype)
 
 
 def rope(x, positions, theta: float):

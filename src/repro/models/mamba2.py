@@ -48,9 +48,10 @@ def init_mamba(key, d_model: int, d_in: int, N: int, hd: int, w: int, dtype=jnp.
 
 
 def _split(pr: MambaParams, u, d_in: int, N: int, nh: int):
-    zxbcdt = u @ pr.in_proj
-    z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * N], axis=-1)
-    return z, xbc, dt
+    with jax.named_scope("mamba.in_proj"):
+        zxbcdt = u @ pr.in_proj
+        z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * N], axis=-1)
+        return z, xbc, dt
 
 
 def _gated_norm(y, z, g, eps=1e-5):
@@ -71,13 +72,33 @@ def mamba_forward(pr: MambaParams, u, *, N: int, hd: int, chunk: int, return_sta
     w = pr.conv_w.shape[0]
 
     z, xbc, dt = _split(pr, u, d_in, N, nh)
-    # causal depthwise conv over feature-grouped [x|B|C]
-    pad = jnp.zeros((B, w - 1, xbc.shape[-1]), xbc.dtype)
-    xp = jnp.concatenate([pad, xbc], axis=1)
-    xc = sum(xp[:, i : i + S] * pr.conv_w[i] for i in range(w)) + pr.conv_b
-    xc = constrain(jax.nn.silu(xc), "B", None, "M")
-    x, Bm, Cm = jnp.split(xc, [d_in, d_in + N], axis=-1)
+    with jax.named_scope("mamba.conv"):
+        # causal depthwise conv over feature-grouped [x|B|C]
+        pad = jnp.zeros((B, w - 1, xbc.shape[-1]), xbc.dtype)
+        xp = jnp.concatenate([pad, xbc], axis=1)
+        xc = sum(xp[:, i : i + S] * pr.conv_w[i] for i in range(w)) + pr.conv_b
+        xc = constrain(jax.nn.silu(xc), "B", None, "M")
+        x, Bm, Cm = jnp.split(xc, [d_in, d_in + N], axis=-1)
 
+    with jax.named_scope("mamba.ssd"):
+        y, H_final = _ssd(pr, x, Bm, Cm, dt, hd=hd, chunk=chunk)
+        y = y.astype(u.dtype)
+    with jax.named_scope("mamba.out"):
+        out = _gated_norm(y, z, pr.norm_g) @ pr.out_proj
+    if not return_state:
+        return out
+    # exact decode-ready state: conv tail = last w-1 *pre-conv* features
+    cache = MambaCache(conv=xbc[:, S - (w - 1) :, :], ssm=H_final)
+    return out, cache
+
+
+def _ssd(pr: MambaParams, x, Bm, Cm, dt, *, hd: int, chunk: int):
+    """Chunked SSD over x [B, S, d_in] with B/C [B, S, N] and raw dt
+    [B, S, nh]: (y [B, S, d_in] f32 with the D skip, final state
+    [B, nh, N, hd] f32)."""
+    B, S, d_in = x.shape
+    N = Bm.shape[-1]
+    nh = d_in // hd
     a = -jnp.exp(pr.a_log.astype(jnp.float32))  # [nh], negative
     dt = jax.nn.softplus(dt.astype(jnp.float32) + pr.dt_bias)  # [B,S,nh]
 
@@ -127,14 +148,7 @@ def mamba_forward(pr: MambaParams, u, *, N: int, hd: int, chunk: int, return_sta
 
     y = (y_intra + y_inter).reshape(B, S, nh, hd)
     y = y + pr.D[None, None, :, None] * x.reshape(B, S, nh, hd).astype(jnp.float32)
-    y = y.reshape(B, S, d_in).astype(u.dtype)
-    y = _gated_norm(y, z, pr.norm_g)
-    out = y @ pr.out_proj
-    if not return_state:
-        return out
-    # exact decode-ready state: conv tail = last w-1 *pre-conv* features
-    cache = MambaCache(conv=xbc[:, S - (w - 1) :, :], ssm=H_final)
-    return out, cache
+    return y.reshape(B, S, d_in), H_final
 
 
 class MambaCache(NamedTuple):
@@ -160,20 +174,23 @@ def mamba_decode_step(pr: MambaParams, u, cache: MambaCache, *, N: int, hd: int)
     z, xbc, dt = _split(pr, u[:, None, :], d_in, N, nh)
     z, xbc, dt = z[:, 0], xbc[:, 0], dt[:, 0]
 
-    conv_in = jnp.concatenate([cache.conv, xbc[:, None, :]], axis=1)  # [B,w,*]
-    xc = jnp.einsum("bwf,wf->bf", conv_in, pr.conv_w) + pr.conv_b
-    xc = jax.nn.silu(xc)
-    x, Bm, Cm = jnp.split(xc, [d_in, d_in + N], axis=-1)
+    with jax.named_scope("mamba.conv"):
+        conv_in = jnp.concatenate([cache.conv, xbc[:, None, :]], axis=1)  # [B,w,*]
+        xc = jnp.einsum("bwf,wf->bf", conv_in, pr.conv_w) + pr.conv_b
+        xc = jax.nn.silu(xc)
+        x, Bm, Cm = jnp.split(xc, [d_in, d_in + N], axis=-1)
 
-    a = -jnp.exp(pr.a_log.astype(jnp.float32))
-    dtv = jax.nn.softplus(dt.astype(jnp.float32) + pr.dt_bias)  # [B,nh]
-    dec = jnp.exp(a * dtv)  # [B,nh]
+    with jax.named_scope("mamba.ssm_update"):
+        a = -jnp.exp(pr.a_log.astype(jnp.float32))
+        dtv = jax.nn.softplus(dt.astype(jnp.float32) + pr.dt_bias)  # [B,nh]
+        dec = jnp.exp(a * dtv)  # [B,nh]
 
-    xhead = x.reshape(B, nh, hd).astype(jnp.float32)
-    upd = jnp.einsum("bn,bh,bhd->bhnd", Bm.astype(jnp.float32), dtv, xhead)
-    ssm = cache.ssm * dec[..., None, None] + upd
-    y = jnp.einsum("bn,bhnd->bhd", Cm.astype(jnp.float32), ssm)
-    y = y + pr.D[None, :, None] * xhead
-    y = y.reshape(B, d_in).astype(u.dtype)
-    y = _gated_norm(y, z, pr.norm_g)
-    return y @ pr.out_proj, MambaCache(conv=conv_in[:, 1:], ssm=ssm)
+        xhead = x.reshape(B, nh, hd).astype(jnp.float32)
+        upd = jnp.einsum("bn,bh,bhd->bhnd", Bm.astype(jnp.float32), dtv, xhead)
+        ssm = cache.ssm * dec[..., None, None] + upd
+        y = jnp.einsum("bn,bhnd->bhd", Cm.astype(jnp.float32), ssm)
+        y = y + pr.D[None, :, None] * xhead
+        y = y.reshape(B, d_in).astype(u.dtype)
+    with jax.named_scope("mamba.out"):
+        out = _gated_norm(y, z, pr.norm_g) @ pr.out_proj
+    return out, MambaCache(conv=conv_in[:, 1:], ssm=ssm)

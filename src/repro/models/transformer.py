@@ -214,7 +214,8 @@ def _block(cfg: ModelConfig, params_l, window, x, positions, collect: bool):
         return constrain((x + y).astype(in_dtype), "B", None, None), aux, (kv_k, kv_v, conv, ssm)
 
     h = rms_norm(x, params_l["ln1"], cfg.norm_eps)
-    attn_out, (k, v) = _self_attn(cfg, params_l["attn"], h, positions, window)
+    with jax.named_scope("attn"):
+        attn_out, (k, v) = _self_attn(cfg, params_l["attn"], h, positions, window)
     if collect:
         kv_k, kv_v = k, v
     if cfg.family == "hybrid":
@@ -235,7 +236,8 @@ def _block(cfg: ModelConfig, params_l, window, x, positions, collect: bool):
     x = x + attn_out
 
     h2 = rms_norm(x, params_l["ln2"], cfg.norm_eps)
-    mlp_out, aux = _mlp_or_moe(cfg, params_l, h2)
+    with jax.named_scope("mlp"):
+        mlp_out, aux = _mlp_or_moe(cfg, params_l, h2)
     if cfg.alt_local_global:
         mlp_out = rms_norm(mlp_out, params_l["ln2_post"], cfg.norm_eps)
     x = constrain((x + mlp_out).astype(in_dtype), "B", None, None)
@@ -250,9 +252,10 @@ def forward(cfg: ModelConfig, params, tokens, media=None, *, collect: bool = Fal
     """
     B, S = tokens.shape
     adt = jnp.bfloat16 if cfg.quant.activations == "bf16" else jnp.float32
-    x = constrain(params["embed"][tokens].astype(adt), "B", None, None)
-    if cfg.alt_local_global:
-        x = x * (cfg.d_model**0.5)  # gemma2 embedding scaling
+    with jax.named_scope("embed"):
+        x = constrain(params["embed"][tokens].astype(adt), "B", None, None)
+        if cfg.alt_local_global:
+            x = x * (cfg.d_model**0.5)  # gemma2 embedding scaling
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     windows = _layer_windows(cfg)
 
@@ -284,24 +287,29 @@ def forward(cfg: ModelConfig, params, tokens, media=None, *, collect: bool = Fal
             self_p, wins, cross_p = xs
             (x, aux), cache_bits = lax.scan(step, (x, aux), (self_p, wins))
             h = rms_norm(x, cross_p["ln"], cfg.norm_eps)
-            gate = jnp.tanh(cross_p["gate"]).astype(x.dtype)
-            x = (x + gate * _cross_attn(cfg, cross_p, h, media_emb)).astype(h.dtype)
+            with jax.named_scope("attn"):
+                gate = jnp.tanh(cross_p["gate"]).astype(x.dtype)
+                x = (x + gate * _cross_attn(cfg, cross_p, h, media_emb)).astype(h.dtype)
             return (x, aux), cache_bits
 
         vb = jax.checkpoint(vlm_block) if cfg.remat == "block" else vlm_block
-        (x, aux), cache_bits = lax.scan(vb, (x, jnp.float32(0.0)), (self_stacked, win_stacked, cross))
+        with jax.named_scope("layers"):
+            (x, aux), cache_bits = lax.scan(
+                vb, (x, jnp.float32(0.0)), (self_stacked, win_stacked, cross))
         if collect:  # [Lc, kk, ...] -> [L, ...]
             cache_bits = jax.tree.map(
                 lambda a: a.reshape((Lc * kk,) + a.shape[2:]) if a.ndim >= 2 else a,
                 cache_bits,
             )
     else:
-        (x, aux), cache_bits = lax.scan(step, (x, jnp.float32(0.0)), (layers, windows))
+        with jax.named_scope("layers"):
+            (x, aux), cache_bits = lax.scan(step, (x, jnp.float32(0.0)), (layers, windows))
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-    logits = constrain(softcap(logits, cfg.logit_softcap), "B", None, "M")
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+        logits = constrain(softcap(logits, cfg.logit_softcap), "B", None, "M")
     return logits, aux, (cache_bits if collect else None)
 
 
@@ -314,12 +322,13 @@ def loss_fn(cfg: ModelConfig, params, batch, *, aux_weight: float = 0.01):
     on llama3.2-3b train_4k); the contraction reduces shard-locally."""
     tokens = batch["tokens"]
     logits, aux, _ = forward(cfg, params, tokens, media=batch.get("media"))
-    tgt = tokens[:, 1:]
-    lg = logits[:, :-1]
-    logz = jax.scipy.special.logsumexp(lg, axis=-1)
-    oh = jax.nn.one_hot(tgt, lg.shape[-1], dtype=lg.dtype)
-    gold = jnp.einsum("bsv,bsv->bs", lg, oh)
-    ce = (logz - gold).mean()
+    with jax.named_scope("head"):
+        tgt = tokens[:, 1:]
+        lg = logits[:, :-1]
+        logz = jax.scipy.special.logsumexp(lg, axis=-1)
+        oh = jax.nn.one_hot(tgt, lg.shape[-1], dtype=lg.dtype)
+        gold = jnp.einsum("bsv,bsv->bs", lg, oh)
+        ce = (logz - gold).mean()
     return ce + aux_weight * aux, {"ce": ce, "aux": aux}
 
 
@@ -352,45 +361,47 @@ def _encode_cache(cfg, x):
     HBM/cache bit rot), and under a telemetry capture each append counts
     its special codes (``kv.specials.<fmt>``) — poisoned K/V projections
     show up here one decode step before they show up as NaN logits."""
-    fmt = cfg.quant.kv_cache
-    wf = wire_format(fmt)
-    if wf.is_block_scaled:
-        from repro.quant import blockscale
+    with jax.named_scope("kv.encode"):
+        fmt = cfg.quant.kv_cache
+        wf = wire_format(fmt)
+        if wf.is_block_scaled:
+            from repro.quant import blockscale
 
-        bits = encode_jnp_fast(
-            blockscale.pad_block(x.astype(jnp.float32)), wf.name
-        )
-    elif wf.family in ("takum", "ofp8"):
-        bits = encode_jnp_fast(x.astype(jnp.float32), wf.name)
-    else:
-        bits = x.astype(jnp.bfloat16 if fmt == "bf16" else jnp.float32)
-        if fmt == "f32":
-            return bits  # exact storage: nothing to corrupt or count
-    bits = faults.corrupt_payload(bits, wf.name)
-    if telemetry.enabled():
-        telemetry.emit(f"kv.appends.{wf.name}", jnp.float32(1))
-        telemetry.emit(f"kv.specials.{wf.name}", count_specials(bits, wf.name))
-        telemetry.emit(
-            f"kv.bytes.{wf.name}", float(bits.size * bits.dtype.itemsize)
-        )
-    return bits
+            bits = encode_jnp_fast(
+                blockscale.pad_block(x.astype(jnp.float32)), wf.name
+            )
+        elif wf.family in ("takum", "ofp8"):
+            bits = encode_jnp_fast(x.astype(jnp.float32), wf.name)
+        else:
+            bits = x.astype(jnp.bfloat16 if fmt == "bf16" else jnp.float32)
+            if fmt == "f32":
+                return bits  # exact storage: nothing to corrupt or count
+        bits = faults.corrupt_payload(bits, wf.name)
+        if telemetry.enabled():
+            telemetry.emit(f"kv.appends.{wf.name}", jnp.float32(1))
+            telemetry.emit(f"kv.specials.{wf.name}", count_specials(bits, wf.name))
+            telemetry.emit(
+                f"kv.bytes.{wf.name}", float(bits.size * bits.dtype.itemsize)
+            )
+        return bits
 
 
 def _decode_cache(cfg, bits, hd: int | None = None):
     """Cache storage -> f32.  ``hd`` is the logical head dim, needed to
     slice the zero padding off a block-scaled payload."""
-    fmt = cfg.quant.kv_cache
-    wf = wire_format(fmt)
-    if wf.is_block_scaled:
-        from repro.kernels.lut import decode_jnp_fast
+    with jax.named_scope("kv.decode"):
+        fmt = cfg.quant.kv_cache
+        wf = wire_format(fmt)
+        if wf.is_block_scaled:
+            from repro.kernels.lut import decode_jnp_fast
 
-        out = decode_jnp_fast(bits, wf.name)
-        return out if hd is None else out[..., :hd]
-    if is_takum(fmt):
-        return takum_decode(bits, takum_width(fmt))
-    if wf.family == "ofp8":
-        return wf.decode_jnp(bits)
-    return bits.astype(jnp.float32)
+            out = decode_jnp_fast(bits, wf.name)
+            return out if hd is None else out[..., :hd]
+        if is_takum(fmt):
+            return takum_decode(bits, takum_width(fmt))
+        if wf.family == "ofp8":
+            return wf.decode_jnp(bits)
+        return bits.astype(jnp.float32)
 
 
 def _cache_dtype(cfg):
@@ -457,9 +468,10 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
     B = token.shape[0]
     d = cfg.d_model
     adt = jnp.bfloat16 if cfg.quant.activations == "bf16" else jnp.float32
-    x = params["embed"][token].astype(adt)
-    if cfg.alt_local_global:
-        x = x * (d**0.5)
+    with jax.named_scope("embed"):
+        x = params["embed"][token].astype(adt)
+        if cfg.alt_local_global:
+            x = x * (d**0.5)
     pos = cache.pos
     windows = _layer_windows(cfg)
     L = cfg.num_layers
@@ -509,7 +521,8 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
             )
             return (x + y).astype(in_dtype), (k_l, v_l, mc.conv, mc.ssm)
         h = rms_norm(x, params_l["ln1"], cfg.norm_eps)
-        attn_out, k_l, v_l = attn_decode(params_l["attn"], h, k_l, v_l, window)
+        with jax.named_scope("attn"):
+            attn_out, k_l, v_l = attn_decode(params_l["attn"], h, k_l, v_l, window)
         conv_new, ssm_new = conv_l, ssm_l
         if cfg.family == "hybrid":
             y_ssm, mc = mamba_decode_step(
@@ -522,11 +535,12 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
             attn_out = rms_norm(attn_out, params_l["ln1_post"], cfg.norm_eps)
         x = x + attn_out
         h2 = rms_norm(x, params_l["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            mlp_out, _ = _mlp_or_moe(cfg, params_l, h2[:, None, :])
-            mlp_out = mlp_out[:, 0]
-        else:
-            mlp_out, _ = _mlp_or_moe(cfg, params_l, h2)
+        with jax.named_scope("mlp"):
+            if cfg.family == "moe":
+                mlp_out, _ = _mlp_or_moe(cfg, params_l, h2[:, None, :])
+                mlp_out = mlp_out[:, 0]
+            else:
+                mlp_out, _ = _mlp_or_moe(cfg, params_l, h2)
         if cfg.alt_local_global:
             mlp_out = rms_norm(mlp_out, params_l["ln2_post"], cfg.norm_eps)
         return (x + mlp_out).astype(in_dtype), (k_l, v_l, conv_new, ssm_new)
@@ -549,25 +563,30 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
             self_p, wins, k_b, v_b, cz, cross_p = xs
             x, (k_new, v_new, _, _) = lax.scan(layer_step, x, (self_p, wins, k_b, v_b, cz, cz))
             h = rms_norm(x, cross_p["ln"], cfg.norm_eps)
-            gate = jnp.tanh(cross_p["gate"]).astype(x.dtype)
-            x = (x + gate * _cross_attn(cfg, cross_p, h[:, None], media_emb)[:, 0]).astype(h.dtype)
+            with jax.named_scope("attn"):
+                gate = jnp.tanh(cross_p["gate"]).astype(x.dtype)
+                x = (x + gate * _cross_attn(cfg, cross_p, h[:, None], media_emb)[:, 0]
+                     ).astype(h.dtype)
             return x, (k_new, v_new)
 
-        x, (k_all, v_all) = lax.scan(
-            vlm_step, x, (self_stacked, win_s, kc, vc, conv_s, cross)
-        )
+        with jax.named_scope("layers"):
+            x, (k_all, v_all) = lax.scan(
+                vlm_step, x, (self_stacked, win_s, kc, vc, conv_s, cross)
+            )
         new_cache = cache._replace(
             k=k_all.reshape(cache.k.shape), v=v_all.reshape(cache.v.shape), pos=pos + 1
         )
     else:
-        x, outs = lax.scan(
-            layer_step, x, (layers, windows, cache.k, cache.v, L_conv, L_ssm)
-        )
+        with jax.named_scope("layers"):
+            x, outs = lax.scan(
+                layer_step, x, (layers, windows, cache.k, cache.v, L_conv, L_ssm)
+            )
         new_cache = cache._replace(k=outs[0], v=outs[1], pos=pos + 1)
         if cfg.family in ("ssm", "hybrid"):
             new_cache = new_cache._replace(conv=outs[2], ssm=outs[3])
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap((x @ head.astype(x.dtype)).astype(jnp.float32), cfg.logit_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = softcap((x @ head.astype(x.dtype)).astype(jnp.float32), cfg.logit_softcap)
     return logits, new_cache

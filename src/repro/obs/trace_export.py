@@ -7,18 +7,16 @@ Two formats, one :func:`repro.core.telemetry.snapshot` source:
   machine-joinable record a CI run archives.
 * :func:`export_chrome_trace` — the Chrome Trace Event JSON format
   (``{"traceEvents": [...]}``), loadable by Perfetto
-  (https://ui.perfetto.dev) and ``chrome://tracing``.  Spans become
+  (https://ui.perfetto.dev) and ``chrome://tracing``.  Host spans become
   complete ("ph": "X") events with microsecond timestamps relative to the
-  earliest span; the span category (``kernel`` / ``collective`` / ``step``)
-  maps to the event ``cat``, and each host thread becomes a trace ``tid``.
+  earliest span; the span category (``step`` / ``bench`` / ...) maps to
+  the event ``cat``, and each host thread becomes a trace ``tid``.
   Counters/gauges/histograms ride along under ``otherData`` so one file
   carries the whole capture.
 
-Span timing honesty: host spans are real wall clock; trace spans are
-"callback clock" (begin/end debug-callback arrival — see
-:mod:`repro.core.telemetry`), good for ordering and coarse duration, not
-for ns-level attribution.  The export marks the distinction via the span
-category the instrumentation chose.
+Spans are host wall clock.  Device time per region is read from a
+profiler trace by the regions' ``jax.named_scope`` names, not from here
+(DESIGN.md §9).
 
 ``parse`` helpers (:func:`load_jsonl`, :func:`load_chrome_trace`,
 :func:`validate_chrome_trace`) close the loop for the tier-1 obs smoke:
@@ -100,7 +98,6 @@ def chrome_trace(snapshot: dict | None = None) -> dict:
             "counters": snap["counters"],
             "gauges": snap["gauges"],
             "hists": snap["hists"],
-            "dropped_spans": snap["dropped_spans"],
         },
     }
 

@@ -1,17 +1,19 @@
 """repro.obs: the unified observability subsystem (DESIGN.md §9).
 
 In-process tests cover the metrics registry (counters/gauges/histograms,
-nested capture scopes, thread-safety, the zero-op trace-time gate), the
-statistics core (seeded bootstrap CIs + the CI-overlap gate), and the
-JSONL / Chrome-trace exports with parse-back.  Multiplicity under
-shard_map and the end-to-end acceptance (captured multi-pod train step ->
-spans -> trace export -> parse-back) need real devices and trace-cache
-isolation, so they run in subprocesses on an 8-fake-device mesh (same
-idiom as tests/test_faults.py).
+nested capture scopes, thread-safety, the zero-op trace-time gate, named
+scopes in the HLO metadata), the statistics core (seeded bootstrap CIs +
+the CI-overlap gate), and the JSONL / Chrome-trace exports with
+parse-back.  Multiplicity under shard_map and the end-to-end acceptance
+(captured multi-pod train step -> host spans, counters and scope names ->
+trace export -> parse-back) need real devices and trace-cache isolation,
+so they run in subprocesses on an 8-fake-device mesh (same idiom as
+tests/test_faults.py).
 """
 
 import json
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -31,6 +33,12 @@ from repro.obs import trace_export
 _SRC = os.path.join(os.path.dirname(__file__), "../src")
 
 
+def _op_names(fn, *args) -> list[str]:
+    """The ``op_name`` metadata of ``jit(fn)``'s lowered HLO."""
+    text = jax.jit(fn).lower(*args).as_text(dialect="hlo", debug_info=True)
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
 def _run(child: str, timeout=500) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC
@@ -46,6 +54,7 @@ _PRE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
+import re
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -151,9 +160,60 @@ def test_host_span_records_wall_clock_and_args():
     assert sp["args"] == {"step": 3}
 
 
-def test_probe_is_one_element():
-    assert telemetry.probe(jnp.ones((4, 5))).size == 1
-    assert telemetry.probe(jnp.zeros((0,))).size == 1
+def test_host_span_always_enters_a_profiler_annotation(monkeypatch):
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with telemetry.host_span("t.free"):
+        pass
+    with telemetry.capture():
+        with telemetry.host_span("t.kept"):
+            pass
+        kept = [s["name"] for s in telemetry.spans()]
+    # on the profiler's clock either way; in the registry only under capture
+    assert entered == ["t.free", "t.kept"]
+    assert kept == ["t.kept"]
+
+
+# ---------------------------------------------------------------------------
+# named scopes on the attention-family serving steps
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_hybrid_serving_steps_name_every_layer(kind):
+    from repro import configs
+    from repro.dist import step as dstep
+    from repro.launch.mesh import parse_mesh
+    from repro.models import transformer as T
+    from repro.quant.policy import POLICIES
+
+    cfg = configs.get_smoke("hymba_1_5b").with_(quant=POLICIES["takum"])
+    mesh = parse_mesh("1x1")
+    params = dstep.serve_param_shapes(cfg)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    if kind == "prefill":
+        step = dstep.make_prefill_step(cfg, mesh, cache_len=24)
+        args = (params, {"tokens": tokens})
+    else:
+        cache = jax.eval_shape(lambda: T.init_cache(cfg, 2, 24))
+        step = dstep.make_serve_step(cfg, mesh)
+        args = (params, {"token": jax.ShapeDtypeStruct((2,), jnp.int32)}, cache)
+    scopes = {part for path in _op_names(step, *args) for part in path.split("/")}
+    want = {"serve.weights", "embed", "layers", "norm", "attn", "kv.encode", "mlp",
+            "mamba.in_proj", "mamba.conv", "mamba.out", "head"}
+    want |= {"mamba.ssd"} if kind == "prefill" else {"kv.decode", "mamba.ssm_update"}
+    assert want <= scopes, sorted(want - scopes)
 
 
 # ---------------------------------------------------------------------------
@@ -169,9 +229,8 @@ def _make_instrumented():
         telemetry.emit("z.c", jnp.sum(x))
         telemetry.emit_gauge("z.g", jnp.max(x))
         telemetry.emit_hist("z.h", jnp.min(x))
-        with telemetry.trace_span("z.s", cat="kernel") as sp:
+        with jax.named_scope("z.s"):
             y = x * 2
-            sp.dep = telemetry.probe(y)
         return y
 
     return instrumented
@@ -183,6 +242,8 @@ def test_uncaptured_trace_carries_zero_callback_ops():
     # not merely gated callbacks: NO leftover instrumentation ops at all —
     # the jaxpr is exactly the payload computation
     assert jaxpr.count("mul") == 1
+    # the named scope is metadata on that op, captured or not
+    assert any("/z.s/mul" in n for n in _op_names(_make_instrumented(), jnp.ones(8)))
 
 
 def test_captured_trace_carries_the_callbacks():
@@ -200,8 +261,10 @@ def test_emissions_flushed_by_capture_exit():
     assert ctrs["z.c"] == 24.0
     assert telemetry.gauges()["z.g"] == 2.0
     assert telemetry.hists()["z.h"]["count"] == 2
-    spans = [s for s in telemetry.spans() if s["name"] == "z.s"]
-    assert len(spans) + telemetry.dropped_spans() >= 2
+    # the scope is HLO metadata, not a recorded span
+    assert not telemetry.spans()
+    with telemetry.capture():
+        assert any("/z.s/mul" in n for n in _op_names(_make_instrumented(), jnp.ones(8)))
 
 
 # ---------------------------------------------------------------------------
@@ -316,30 +379,31 @@ mesh = jax.make_mesh((8,), ("x",))
 def body(x):
     telemetry.emit("m.count", jnp.float32(1))
     telemetry.emit_hist("m.h", jnp.sum(x))
-    with telemetry.trace_span("m.span", cat="test") as sp:
+    with jax.named_scope("m.span"):
         y = x * 2
-        sp.dep = telemetry.probe(y)
     return y
 
 with telemetry.capture() as ctrs:
-    f = jax.shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-                      check_vma=False)
-    jax.block_until_ready(jax.jit(f)(jnp.arange(16.0)))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                              check_vma=False))
+    jax.block_until_ready(f(jnp.arange(16.0)))
+    hlo = f.lower(jnp.arange(16.0)).as_text(dialect="hlo", debug_info=True)
 
 snap = telemetry.snapshot()
 print(json.dumps({
     "count": snap["counters"]["m.count"],
     "hist_count": snap["hists"]["m.h"]["count"],
-    "spans": len([s for s in snap["spans"] if s["name"] == "m.span"]),
-    "dropped": snap["dropped_spans"],
+    "scoped": re.findall(r'op_name="([^"]*m\\.span/[^"]*)"', hlo),
+    "spans": len(snap["spans"]),
 }))
 """)
-    # every device emits: counters sum 8 ones, the hist takes 8 samples,
-    # and 8 begin/end pairs arrive (an end racing ahead of its begin is
-    # counted as dropped, never silently lost)
+    # every device emits: counters sum 8 ones and the hist takes 8 samples;
+    # the scope is metadata on the one program all 8 devices run, so it
+    # names the region once and records nothing
     assert out["count"] == 8.0
     assert out["hist_count"] == 8
-    assert out["spans"] + out["dropped"] == 8
+    assert any(n.endswith("m.span/mul") for n in out["scoped"]), out
+    assert out["spans"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -376,10 +440,21 @@ batch = jax.device_put(batch, shd.named(mesh, bspec))
 step = jax.jit(dstep.make_train_step(cfg, mesh))
 
 x = jax.random.normal(jax.random.PRNGKey(2), (64, 128))
+codec = lambda x: ops.decode(ops.encode(x, "t8"), "t8")
 with telemetry.capture() as ctrs:
-    state, m = step(state, batch)
-    dec = ops.decode(ops.encode(x, "t8"), "t8")
-    jax.block_until_ready((m["loss"], dec))
+    with telemetry.host_span("loop.step", cat="step", step=0):
+        state, m = step(state, batch)
+        jax.block_until_ready(m["loss"])
+    dec = codec(x)
+    jax.block_until_ready(dec)
+hlo = (step.lower(state, batch).as_text(dialect="hlo", debug_info=True)
+       + jax.jit(codec).lower(x).as_text(dialect="hlo", debug_info=True))
+scopes = set()
+for path in re.findall(r'op_name="([^"]*)"', hlo):
+    for part in path.split("/"):
+        while "(" in part and part.endswith(")"):
+            part = part[part.index("(") + 1:-1]
+        scopes.add(part)
 
 n_jsonl = obs.export_jsonl({jsonl!r})
 n_spans = obs.export_chrome_trace({trace!r})
@@ -387,6 +462,7 @@ evs = obs.validate_chrome_trace(obs.load_chrome_trace({trace!r}))
 lines = obs.load_jsonl({jsonl!r})
 snap = telemetry.snapshot()
 print(json.dumps({{
+    "scopes": sorted(scopes),
     "cats": sorted({{e["cat"] for e in evs}}),
     "names": sorted({{e["name"] for e in evs}}),
     "n_spans": n_spans,
@@ -399,13 +475,13 @@ print(json.dumps({{
     "grad_norm_count": snap["hists"]["step.grad_norm"]["count"],
 }}))
 """)
-    # acceptance: the trace holds kernel-dispatch, collective-hop, AND
-    # train-step spans, and both exports parse back
-    assert {"kernel", "collective", "step"} <= set(out["cats"]), out
-    assert any(n.startswith("kernel.decode") for n in out["names"]), out
-    assert any(n.startswith("wire.hop") for n in out["names"]), out
-    assert "step.train" in out["names"], out
-    assert out["n_spans"] >= 3
+    # acceptance: the compiled programs name kernel dispatch, the
+    # collective ring and its hops, and the step's layers; the exports
+    # carry the host span and parse back
+    assert {"kernel.encode.t8", "kernel.decode.t8", "wire.ring.t8", "wire.hop.t8",
+            "layers", "optimizer", "grad_stats"} <= set(out["scopes"]), out
+    assert out["cats"] == ["step"] and out["names"] == ["loop.step"], out
+    assert out["n_spans"] == 1
     assert {"counter", "hist", "span"} <= set(out["jsonl_kinds"]), out
     # online metrics wired through the same capture
     assert out["kernel_calls"] == 1.0  # eager dispatch: multiplicity 1
