@@ -2,8 +2,8 @@
 
 Chunked SSD for training/prefill: within-chunk quadratic attention-like term
 plus an inter-chunk state recurrence (lax.scan over chunks), O(S * Q) memory.
-Decode: constant-size recurrent state per layer
-(ssm state [B, nh, hd, N] + conv tail [B, w-1, d_conv_in]).
+Decode: constant-size recurrent state per layer (ssm state [B, nh, N, hd]
+or [B, nh, hd, N], see ``state_axes``, + conv tail [B, w-1, d_conv_in]).
 
 Scalar-identity A per head (the SSD restriction), grouped B/C (G=1 group),
 causal depthwise conv over [x, B, C] as in the reference implementation.
@@ -88,6 +88,8 @@ def mamba_forward(pr: MambaParams, u, *, N: int, hd: int, chunk: int, return_sta
     if not return_state:
         return out
     # exact decode-ready state: conv tail = last w-1 *pre-conv* features
+    if state_axes(N, hd) == "dn":
+        H_final = jnp.swapaxes(H_final, -1, -2)
     cache = MambaCache(conv=xbc[:, S - (w - 1) :, :], ssm=H_final)
     return out, cache
 
@@ -151,16 +153,31 @@ def _ssd(pr: MambaParams, x, Bm, Cm, dt, *, hd: int, chunk: int):
     return y.reshape(B, S, d_in), H_final
 
 
+def state_axes(N: int, hd: int) -> str:
+    """Order of the stored SSM state's last two axes, in einsum letters:
+    ``"nd"`` is [.., N, hd], ``"dn"`` is [.., hd, N].  The larger of N and
+    hd goes minor, where the TPU's (8, 128) tiles put it on the lanes: the
+    decode update then works in the layout the state is stored in, with no
+    relayout of the state on its way in or out.  A tie keeps ``"nd"``."""
+    return "dn" if N > hd else "nd"
+
+
 class MambaCache(NamedTuple):
-    conv: jax.Array  # [B, w-1, d_in + 2N]
-    ssm: jax.Array  # [B, nh, N, hd] float32 (or takum-packed by the cache layer)
+    """Decode state of one layer: the conv tail [B, w-1, d_in + 2N] and the
+    f32 SSM state, [B, nh, N, hd] or [B, nh, hd, N] by ``state_axes``."""
+
+    conv: jax.Array
+    ssm: jax.Array
 
 
 def init_mamba_cache(B: int, d_in: int, N: int, hd: int, w: int, dtype=jnp.float32):
+    """Zero decode state; the SSM state's last two axes in ``state_axes``
+    order (N=128, hd=64 stores [B, nh, hd, N]; N=16, hd=64 [B, nh, N, hd])."""
     nh = d_in // hd
+    last = (hd, N) if state_axes(N, hd) == "dn" else (N, hd)
     return MambaCache(
         conv=jnp.zeros((B, w - 1, d_in + 2 * N), dtype),
-        ssm=jnp.zeros((B, nh, N, hd), jnp.float32),
+        ssm=jnp.zeros((B, nh) + last, jnp.float32),
     )
 
 
@@ -186,9 +203,10 @@ def mamba_decode_step(pr: MambaParams, u, cache: MambaCache, *, N: int, hd: int)
         dec = jnp.exp(a * dtv)  # [B,nh]
 
         xhead = x.reshape(B, nh, hd).astype(jnp.float32)
-        upd = jnp.einsum("bn,bh,bhd->bhnd", Bm.astype(jnp.float32), dtv, xhead)
+        st = "bh" + state_axes(N, hd)
+        upd = jnp.einsum(f"bn,bh,bhd->{st}", Bm.astype(jnp.float32), dtv, xhead)
         ssm = cache.ssm * dec[..., None, None] + upd
-        y = jnp.einsum("bn,bhnd->bhd", Cm.astype(jnp.float32), ssm)
+        y = jnp.einsum(f"bn,{st}->bhd", Cm.astype(jnp.float32), ssm)
         y = y + pr.D[None, :, None] * xhead
         y = y.reshape(B, d_in).astype(u.dtype)
     with jax.named_scope("mamba.out"):

@@ -342,7 +342,7 @@ class KVCache(NamedTuple):
     v: Any
     pos: Any  # [] int32
     conv: Any = _EMPTY  # [L, B, w-1, feat] (ssm/hybrid)
-    ssm: Any = _EMPTY  # [L, B, nh, N, hd] f32
+    ssm: Any = _EMPTY  # [L, B, nh, N, hd] or [L, B, nh, hd, N] f32 (mamba2.state_axes)
 
 
 def _encode_cache(cfg, x):
@@ -510,27 +510,32 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
         o = jnp.einsum("bhqs,bshd->bqhd", p, vv).reshape(B, 1, H * hd).astype(h.dtype)
         return linear(o, lp["wo"])[:, 0], k_layer, v_layer
 
-    def layer_step(x, xs):
+    def mamba(params_l, h, conv, ssm, i):
+        # layer i's slice of the stacked state, updated in place in the stack
+        y, mc = mamba_decode_step(
+            params_l["ssm"], h,
+            MambaCache(lax.dynamic_index_in_dim(conv, i, keepdims=False),
+                       lax.dynamic_index_in_dim(ssm, i, keepdims=False)),
+            N=cfg.ssm_state, hd=cfg.ssm_head_dim,
+        )
+        return (y, lax.dynamic_update_index_in_dim(conv, mc.conv, i, 0),
+                lax.dynamic_update_index_in_dim(ssm, mc.ssm, i, 0))
+
+    def layer_step(carry, xs):
+        # the stacked conv/SSM state rides in the carry (ssm, hybrid), so the
+        # loop updates the donated cache in place; K/V go through xs -> ys
+        x, conv, ssm = carry
         in_dtype = x.dtype
-        params_l, window, k_l, v_l, conv_l, ssm_l = xs
-        if cfg.family == "ssm":
-            h = rms_norm(x, params_l["ln1"], cfg.norm_eps)
-            y, mc = mamba_decode_step(
-                params_l["ssm"], h, MambaCache(conv_l, ssm_l),
-                N=cfg.ssm_state, hd=cfg.ssm_head_dim,
-            )
-            return (x + y).astype(in_dtype), (k_l, v_l, mc.conv, mc.ssm)
+        params_l, window, k_l, v_l, i = xs
         h = rms_norm(x, params_l["ln1"], cfg.norm_eps)
+        if cfg.family == "ssm":
+            y, conv, ssm = mamba(params_l, h, conv, ssm, i)
+            return ((x + y).astype(in_dtype), conv, ssm), (k_l, v_l)
         with jax.named_scope("attn"):
             attn_out, k_l, v_l = attn_decode(params_l["attn"], h, k_l, v_l, window)
-        conv_new, ssm_new = conv_l, ssm_l
         if cfg.family == "hybrid":
-            y_ssm, mc = mamba_decode_step(
-                params_l["ssm"], h, MambaCache(conv_l, ssm_l),
-                N=cfg.ssm_state, hd=cfg.ssm_head_dim,
-            )
+            y_ssm, conv, ssm = mamba(params_l, h, conv, ssm, i)
             attn_out = 0.5 * (attn_out + y_ssm)
-            conv_new, ssm_new = mc.conv, mc.ssm
         if cfg.alt_local_global:
             attn_out = rms_norm(attn_out, params_l["ln1_post"], cfg.norm_eps)
         x = x + attn_out
@@ -543,11 +548,10 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
                 mlp_out, _ = _mlp_or_moe(cfg, params_l, h2)
         if cfg.alt_local_global:
             mlp_out = rms_norm(mlp_out, params_l["ln2_post"], cfg.norm_eps)
-        return (x + mlp_out).astype(in_dtype), (k_l, v_l, conv_new, ssm_new)
+        return ((x + mlp_out).astype(in_dtype), conv, ssm), (k_l, v_l)
 
     layers = params["layers"]
-    L_conv = cache.conv if cache.conv.size else jnp.zeros((L, 1), jnp.float32)
-    L_ssm = cache.ssm if cache.ssm.size else jnp.zeros((L, 1), jnp.float32)
+    idx = jnp.arange(L)
 
     if cfg.family == "vlm":
         kk_ = cfg.cross_attn_every
@@ -557,11 +561,11 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
         kc = cache.k.reshape((Lc, kk_) + cache.k.shape[1:])
         vc = cache.v.reshape((Lc, kk_) + cache.v.shape[1:])
         cross = params["cross_layers"]
-        conv_s = jnp.zeros((Lc, kk_, 1), jnp.float32)
 
         def vlm_step(x, xs):
-            self_p, wins, k_b, v_b, cz, cross_p = xs
-            x, (k_new, v_new, _, _) = lax.scan(layer_step, x, (self_p, wins, k_b, v_b, cz, cz))
+            self_p, wins, k_b, v_b, idx_b, cross_p = xs
+            (x, _, _), (k_new, v_new) = lax.scan(
+                layer_step, (x, _EMPTY, _EMPTY), (self_p, wins, k_b, v_b, idx_b))
             h = rms_norm(x, cross_p["ln"], cfg.norm_eps)
             with jax.named_scope("attn"):
                 gate = jnp.tanh(cross_p["gate"]).astype(x.dtype)
@@ -571,19 +575,17 @@ def decode_step(cfg: ModelConfig, params, token, cache: KVCache, media=None):
 
         with jax.named_scope("layers"):
             x, (k_all, v_all) = lax.scan(
-                vlm_step, x, (self_stacked, win_s, kc, vc, conv_s, cross)
+                vlm_step, x, (self_stacked, win_s, kc, vc, idx.reshape(Lc, kk_), cross)
             )
         new_cache = cache._replace(
             k=k_all.reshape(cache.k.shape), v=v_all.reshape(cache.v.shape), pos=pos + 1
         )
     else:
         with jax.named_scope("layers"):
-            x, outs = lax.scan(
-                layer_step, x, (layers, windows, cache.k, cache.v, L_conv, L_ssm)
+            (x, conv, ssm), (k_all, v_all) = lax.scan(
+                layer_step, (x, cache.conv, cache.ssm), (layers, windows, cache.k, cache.v, idx)
             )
-        new_cache = cache._replace(k=outs[0], v=outs[1], pos=pos + 1)
-        if cfg.family in ("ssm", "hybrid"):
-            new_cache = new_cache._replace(conv=outs[2], ssm=outs[3])
+        new_cache = cache._replace(k=k_all, v=v_all, conv=conv, ssm=ssm, pos=pos + 1)
 
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)

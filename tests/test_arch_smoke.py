@@ -17,6 +17,16 @@ from repro.models import transformer as T
 from repro.quant.policy import QuantPolicy
 
 ARCHS = configs.ARCHS
+# smoke configs beyond the registry's: mamba2's smoke has N == hd and
+# hymba's N < hd; this one has N > hd, which stores the SSM state [.., hd, N]
+VARIANTS = {"mamba2_780m.n_gt_hd": ("mamba2_780m", {"ssm_state": 32, "ssm_head_dim": 16})}
+
+
+def _smoke(arch):
+    if arch in VARIANTS:
+        base, kw = VARIANTS[arch]
+        return configs.get_smoke(base).with_(**kw)
+    return configs.get_smoke(arch)
 
 
 def _batch(cfg, B=2, S=32, seed=0):
@@ -59,7 +69,7 @@ def test_train_grad_finite(arch):
     assert float(jnp.abs(g["embed"]).max()) > 0
 
 
-@pytest.mark.parametrize("arch", ARCHS)
+@pytest.mark.parametrize("arch", ARCHS + list(VARIANTS))
 @pytest.mark.parametrize("kv_fmt", ["f32", "t16", "t8"])
 def test_prefill_decode_consistency(arch, kv_fmt):
     """decode_step over tokens [S0:S] must match full-forward logits.
@@ -68,7 +78,7 @@ def test_prefill_decode_consistency(arch, kv_fmt):
     drift by quantisation noise (amplified by discrete MoE routing flips) —
     we check rank agreement of the argmax instead.
     """
-    cfg = configs.get_smoke(arch).with_(quant=QuantPolicy(kv_cache=kv_fmt, activations="f32"))
+    cfg = _smoke(arch).with_(quant=QuantPolicy(kv_cache=kv_fmt, activations="f32"))
     if cfg.family == "ssm" and kv_fmt != "f32":
         pytest.skip("ssm has no KV cache (state quantisation tested separately)")
     if cfg.family == "moe":

@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e at hymba_1_5b widths.
+"""The main-path Pallas kernels compile for a TPU v5e at hymba_1_5b widths,
+and the serve step updates the stacked SSM state in place there.
 
 Each test lowers one kernel natively (``interpret=False``) and compiles it
 with the TPU compiler for one chip of a described ``v5e:2x2`` topology — no
@@ -13,6 +14,8 @@ all import this file.  The persistent compilation cache is off around the
 compiles, since an entry compiled for a described chip cannot be read back.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,10 +23,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.core.formats import wire_format
+from repro.dist import step as dstep
 from repro.kernels.takum_attention import takum_decode_attention
 from repro.kernels.takum_codec import takum_decode_2d, takum_encode_2d
 from repro.kernels.takum_matmul import takum_matmul
+from repro.launch.mesh import parse_mesh
+from repro.models import transformer as T
 from repro.quant import blockscale
+from repro.quant.policy import POLICIES
 
 CFG = configs.get("hymba_1_5b")
 BATCH, KV_LEN = 8, 4096
@@ -85,3 +92,36 @@ def test_kernel_compiles_for_v5e(op, fmt, one_chip, no_persistent_cache):
     fn, args = _program(op, fmt, shape)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", ["mamba2_780m", "hymba_1_5b"])
+def test_decode_step_updates_the_ssm_state_in_place(arch, one_chip, no_persistent_cache):
+    """The serve step, cache donated, at published widths cut to 2 layers
+    and a 256-token vocabulary.  The stacked conv and SSM state ride in the
+    decode layer scan's carry, so the compiled step aliases them with its
+    output and copies neither the stack nor a layer's slice of it.  Passed
+    through the scan as xs -> ys they come back as a second whole-state
+    buffer, copied into the donated output, and (where the update computes
+    in another layout, as at mamba2_780m's N=128, hd=64) each layer's slice
+    is relaid on the way in and out.  The CPU backend copies the carried
+    stack whatever the threading, so only this compile can tell them apart."""
+    cfg = configs.get(arch).with_(num_layers=2, vocab_size=256, quant=POLICIES["takum"])
+    B, S = 64, 16
+    put = lambda t: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), t)
+    cache = put(jax.eval_shape(lambda: T.init_cache(cfg, B, S)))
+    step = jax.jit(dstep.make_serve_step(cfg, parse_mesh("1x1")), donate_argnums=(2,))
+    compiled = step.lower(
+        put(dstep.serve_param_shapes(cfg)),
+        {"token": jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)}, cache,
+    ).compile()
+    st = cache.ssm.shape
+    state_shapes = {",".join(map(str, s)) for s in (st, (1,) + st[1:], st[1:])}
+    copied = [s for s in re.findall(r"= f32\[([\d,]+)\]\S* copy\(", compiled.as_text())
+              if s in state_shapes]
+    assert not copied, copied
+    ma = compiled.memory_analysis()
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    assert ma.alias_size_in_bytes >= nbytes(cache.ssm) + nbytes(cache.conv), ma
+    if cfg.family == "ssm":  # the hybrid's temporaries are its attention's and weights'
+        assert ma.temp_size_in_bytes < nbytes(cache.ssm), ma
