@@ -10,6 +10,7 @@ function ``read(record) -> float | None``.  Nothing here names a cell.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import importlib.util
 import json
@@ -61,8 +62,9 @@ def load_cell(name: str, benchmark: dict | None = None) -> dict:
 
 
 def model_dict(cfg: dict) -> dict:
-    """The configuration's model sizes, as ``work`` and the reference read them."""
-    return {k: cfg[k] for k in program.MODEL_FIELDS + ("family",) if k in cfg}
+    """The configuration's model sizes, as ``work`` and the reference read
+    them: those the program's ``ModelConfig`` names, JSON lists as tuples."""
+    return {k: program.hashable(cfg[k]) for k in program.model_fields() if k in cfg}
 
 
 def read_metric(name: str, record: dict):
@@ -127,11 +129,31 @@ def _memory_line(what: str, compiled) -> None:
 
 
 class Tracer:
-    """The profiler around the window when ``--trace 1``."""
+    """The profiler around the window when ``--trace 1``.
 
-    def __init__(self, on: bool, cell: str):
+    Such a run also compiles its programs inside
+    ``bench.scopes.compiled_texts``, from set-up to the window's close
+    (:meth:`programs`): under a cache key that holds their op names, so
+    that the names in the trace are the program's own, and keeping their
+    text for the reduction by scope.  An untraced run compiles, and loads
+    from the cache, exactly as it would without this class.  ``keep``, a
+    directory, also gets the trace and the programs' text."""
+
+    def __init__(self, on: bool, cell: str, keep: str | None = None):
         self.on = on
         self.dir = OUT / "trace" / cell
+        self.keep = keep
+        self.texts: list[str] = []
+        self._compiling = contextlib.ExitStack()
+
+    def programs(self) -> contextlib.ExitStack:
+        """The context of a run's set-up and window (closed with the window
+        when the window closes first)."""
+        if self.on:
+            from bench import scopes
+
+            self._compiling.enter_context(scopes.compiled_texts(self.texts))
+        return self._compiling
 
     def __enter__(self):
         if self.on:
@@ -142,14 +164,32 @@ class Tracer:
     def __exit__(self, *exc):
         if self.on:
             jax.profiler.stop_trace()
+            self._compiling.close()
 
     def reduce(self, steps: int) -> dict | None:
         if not self.on:
             return None
         from bench import trace as tr
 
-        red = tr.reduce(tr.load(tr.find_xplane(str(self.dir))), steps=steps)
+        path = tr.find_xplane(str(self.dir))
+        if self.keep:
+            out = pathlib.Path(self.keep)
+            out.mkdir(parents=True, exist_ok=True)
+            shutil.copy(path, out / "window.xplane.pb")
+            (out / "programs.json").write_text(json.dumps(self.texts))
+        red = self.summarise(tr.load(path), steps)
         shutil.rmtree(self.dir, ignore_errors=True)
+        return red
+
+    def summarise(self, pd, steps: int) -> dict:
+        """``bench.trace.reduce`` of the window, with device time per step by
+        the program's scopes under ``scopes`` (``bench.scopes.reduce``)."""
+        from bench import scopes
+        from bench import trace as tr
+
+        red = tr.reduce(pd, steps=steps)
+        red["scopes"] = scopes.reduce(pd, scopes.program_names(self.texts), steps=steps)
+        log(f"device time per step by scope over {steps} steps:\n{scopes.table(red['scopes'])}")
         return red
 
 
@@ -395,6 +435,9 @@ def run_decode(c, cfg, tr, seed, seconds, tracer, counter, dev, mark_open, contr
     return e2e, record, nums, steps, failed, peak
 
 
+RUNNERS = {"train": run_train, "decode": run_decode}
+
+
 # ---------------------------------------------------------------------------
 # one run
 # ---------------------------------------------------------------------------
@@ -427,9 +470,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         opened["t"] = time.perf_counter()
         return opened["t"]
 
-    runner = {"train": run_train, "decode": run_decode}[tr["kind"]]
-    e2e, record, nums, steps, failed, peak = runner(
-        c, cfg, tr, seed, seconds, tracer, counter, dev, mark_open, control)
+    with tracer.programs():
+        e2e, record, nums, steps, failed, peak = RUNNERS[tr["kind"]](
+            c, cfg, tr, seed, seconds, tracer, counter, dev, mark_open, control)
     e2e["setup_s"] = opened["t"] - t_start
     log(f"compilations inside the window: {counter.n}; peak_bytes_in_use={peak}")
 

@@ -9,6 +9,7 @@ train step (``repro.launch.train.setup``) and the serving steps
 from __future__ import annotations
 
 import dataclasses
+import functools
 import pathlib
 import sys
 
@@ -24,14 +25,22 @@ from repro.launch import train as launch_train  # noqa: E402
 from repro.launch.compile_cache import configure_compile_cache  # noqa: E402,F401
 from repro.launch.mesh import parse_mesh  # noqa: E402
 from repro.models.config import ModelConfig  # noqa: E402
-from repro.models.mamba2 import MambaParams  # noqa: E402
 from repro.optim import adamw_init  # noqa: E402
 from repro.optim.adamw import adamw_update  # noqa: E402
 from repro.quant.policy import POLICIES  # noqa: E402
 from repro.quant.qtensor import QTensor, dequantize  # noqa: E402
 
-MODEL_FIELDS = tuple(f.name for f in dataclasses.fields(ModelConfig)
-                     if f.name not in ("name", "quant"))
+
+def model_fields() -> tuple[str, ...]:
+    """The program's model sizes: ``ModelConfig``'s fields but its name and
+    wire policy."""
+    return tuple(f.name for f in dataclasses.fields(ModelConfig)
+                 if f.name not in ("name", "quant"))
+
+
+def hashable(v):
+    """A configuration value as a hashable one: a JSON list as a tuple."""
+    return tuple(hashable(x) for x in v) if isinstance(v, list) else v
 
 
 def model_config(cfg: dict) -> ModelConfig:
@@ -45,7 +54,7 @@ def model_config(cfg: dict) -> ModelConfig:
         if have != fmt:
             raise ValueError(f"{cfg['name']}: the program's policy stores {surface} "
                              f"as {have!r}, the configuration declares {fmt!r}")
-    fields = {k: cfg[k] for k in MODEL_FIELDS if k in cfg}
+    fields = {k: hashable(cfg[k]) for k in model_fields() if k in cfg}
     return ModelConfig(name=cfg["name"], quant=policy, **fields)
 
 
@@ -60,48 +69,42 @@ def check_optimizer(opt: dict) -> None:
                              f"the configuration declares {opt[k]}")
 
 
-def to_tree(flat: dict) -> dict:
-    """The benchmark's flat weights -> the program's parameter tree."""
-    tree: dict = {"layers": {}}
-    for name, a in flat.items():
-        parts = name.split(".")
-        node = tree
-        for p in parts[:-1]:
-            node = node.setdefault(p, {})
-        node[parts[-1]] = a
-    ssm = tree["layers"].get("ssm")
-    if ssm is not None:
-        tree["layers"]["ssm"] = MambaParams(**ssm)
-    return tree
+def _path_name(path) -> str:
+    """``layers.ssm.in_proj`` for a tree path of dict keys and tuple fields."""
+    return ".".join(str(getattr(k, "key", getattr(k, "name", k))) for k in path)
+
+
+@functools.lru_cache(maxsize=None)
+def _layout(cfgm: ModelConfig):
+    """(tree structure, leaf names, leaf shapes) of the program's parameters."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(dstep.param_shapes(cfgm))
+    return (treedef, tuple(_path_name(p) for p, _ in leaves),
+            tuple(tuple(a.shape) for _, a in leaves))
+
+
+def to_tree(cfgm: ModelConfig, flat: dict):
+    """The benchmark's flat weights -> the program's parameter tree, in the
+    structure of ``dstep.param_shapes``."""
+    treedef, names, _ = _layout(cfgm)
+    return jax.tree.unflatten(treedef, [flat[n] for n in names])
 
 
 def from_tree(tree) -> dict:
     """The program's parameter tree (or a tree of the same structure) -> flat."""
-    out = {}
-
-    def walk(prefix, node):
-        if isinstance(node, MambaParams):
-            node = node._asdict()
-        if isinstance(node, dict):
-            for k, v in node.items():
-                walk(f"{prefix}{k}.", v)
-        else:
-            out[prefix[:-1]] = node
-
-    walk("", tree)
-    return out
+    return {_path_name(p): a for p, a in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
 def check_layout(cfgm: ModelConfig, flat_shapes: dict) -> None:
-    """The benchmark's weights must have the program's tree and shapes."""
-    want = dstep.param_shapes(cfgm)
-    got = to_tree(flat_shapes)
-    if jax.tree.structure(want) != jax.tree.structure(got):
-        raise ValueError(f"parameter tree differs from the program's:\n"
-                         f"{jax.tree.structure(want)}\n{jax.tree.structure(got)}")
-    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
-        if tuple(w.shape) != tuple(g.shape):
-            raise ValueError(f"parameter shape {g.shape} differs from the program's {w.shape}")
+    """The benchmark's weights must have the program's names and shapes."""
+    _, names, shapes = _layout(cfgm)
+    if set(names) != set(flat_shapes):
+        raise ValueError(f"parameters differ from the program's: the benchmark alone has "
+                         f"{sorted(set(flat_shapes) - set(names))}, the program alone "
+                         f"{sorted(set(names) - set(flat_shapes))}")
+    for n, want in zip(names, shapes):
+        if tuple(flat_shapes[n].shape) != want:
+            raise ValueError(f"parameter {n}: shape {tuple(flat_shapes[n].shape)} differs "
+                             f"from the program's {want}")
 
 
 # ---------------------------------------------------------------------------
@@ -130,7 +133,7 @@ def init_train_state(cfgm, make_params, wkey, rng, sspec):
     rng.  The keys are arguments, so every seed runs the same program."""
 
     def init(wkey, rng):
-        params = to_tree(make_params(wkey))
+        params = to_tree(cfgm, make_params(wkey))
         return dstep.TrainState(params=params, opt=adamw_init(params, fmt=cfgm.quant.opt_state),
                                 rng=rng)
 
@@ -157,7 +160,7 @@ def first_moment_norms(state) -> dict:
 def serve_weights(cfgm, make_params, wkey):
     """One jitted call: the benchmark's weights ``make_params(wkey)`` packed
     by the program's ``quantize_params`` into the policy's weight format."""
-    return jax.jit(lambda k: dstep.quantize_params(cfgm, to_tree(make_params(k))))(wkey)
+    return jax.jit(lambda k: dstep.quantize_params(cfgm, to_tree(cfgm, make_params(k))))(wkey)
 
 
 def prefill_step(cfgm, mesh: str, cache_len):

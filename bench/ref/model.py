@@ -26,14 +26,19 @@ def _layers(params):
 
 def forward(c, params, tokens, num: Numerics = F32, start: int = 0):
     """Logits [B, S - start, V] (float32) at positions ``start..S-1`` of
-    ``tokens`` [B, S]."""
+    ``tokens`` [B, S].  A family module with ``layers(c, params, x, num)``
+    runs its own stack (it gets every parameter); else one layer,
+    ``layer(c, w, x, num)``, is scanned over the ``layers.`` stacks."""
     fam = _family(c)
 
     def body(x, w):
         return jax.checkpoint(lambda x, w: fam.layer(c, w, x, num))(x, w), None
 
     x = params["embed"][tokens]
-    x, _ = lax.scan(body, x, _layers(params))
+    if hasattr(fam, "layers"):
+        x = fam.layers(c, params, x, num)
+    else:
+        x, _ = lax.scan(body, x, _layers(params))
     x = rms_norm(x[:, start:], params["final_norm"], c["norm_eps"])
     head = params["embed"].T if c["tie_embeddings"] else params["lm_head"]
     return mm(num, x, head)
@@ -66,7 +71,7 @@ def loss_and_grads(c, params, tokens, num: Numerics = F32, rows: int = 1):
     """Mean cross-entropy over ``tokens`` [B, S] and its gradient, computed
     ``rows`` rows at a time and summed into one buffer (so the device holds
     at most two gradients besides the parameters)."""
-    f = _grad_fn(tuple(sorted((k, v) for k, v in c.items() if not isinstance(v, (dict, list)))), num)
+    f = _grad_fn(tuple(sorted(c.items())), num)
     B, S = tokens.shape
     total, grads = 0.0, None
     for r in range(0, B, rows):

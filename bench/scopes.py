@@ -27,10 +27,10 @@ how much device time each scope takes:
 
     python3 bench/scopes.py --workload <cell> --seed <n> --seconds <s>
 
-runs the cell's set-up and window as ``bench/run.py`` does, with the
-profiler on, prints the table of device time per step by scope and ends
-its output with the reduction as one JSON line.  It stops at the window's
-end: the reference is not run, and the numbers are no benchmark result.
+makes one traced run of the cell, as ``bench/run.py --trace 1`` does (whose
+run record carries the same reduction under ``trace.scopes``, read by the
+``*_ms.*`` per-layer metrics), prints the table of device time per step by
+scope and ends its output with the reduction as one JSON line.
 ``--keep <dir>`` also writes the trace and the compiled programs' text
 there, for :func:`reduce` to read again.
 """
@@ -285,12 +285,8 @@ def table(red: dict) -> str:
 # ---------------------------------------------------------------------------
 
 
-class _WindowDone(Exception):
-    pass
-
-
 @contextlib.contextmanager
-def _compiled_texts(texts: list):
+def compiled_texts(texts: list):
     """Keeps ``as_text()`` of every program compiled through
     ``jax.stages.Lowered.compile`` (the harness compiles its steps so).
 
@@ -317,40 +313,40 @@ def _compiled_texts(texts: list):
         jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
+def program_names(texts: list[str]) -> dict:
+    """:func:`op_names` over the programs' texts, in the order compiled: a
+    later program wins a shared name (the window's decode step, compiled
+    after the prefill)."""
+    names = {}
+    for text in texts:
+        names.update(op_names(text))
+    return names
+
+
+def step_ms(r: dict, kind: str, scope: str) -> float | None:
+    """Device ms per step in ``scope`` from a traced run record of a
+    ``kind`` cell (train or decode); None where the scope is absent."""
+    if r["kind"] != kind or not r["trace"]:
+        return None
+    t = r["trace"]["scopes"]["scopes"].get(scope)
+    return None if t is None else 1e3 * t
+
+
 def run(cell: dict, seed: int, seconds: float, keep: str | None = None) -> dict:
-    """The cell's set-up and traced window; returns ``reduce`` per step."""
-    import json
-    import pathlib
-    import shutil
+    """One traced run of the cell as ``bench/run.py --trace 1`` makes it
+    (reference included); returns the scope reduction per step."""
     import time
 
-    from bench import harness, trace
-
-    texts: list[str] = []
-    result = {}
-
-    class Tracer(harness.Tracer):
-        def reduce(self, steps):
-            path = trace.find_xplane(str(self.dir))
-            if keep:
-                out = pathlib.Path(keep)
-                out.mkdir(parents=True, exist_ok=True)
-                shutil.copy(path, out / "window.xplane.pb")
-                (out / "programs.json").write_text(json.dumps(texts))
-            names = {}
-            for text in texts:  # the window's step, compiled after the prefill, wins a shared name
-                names.update(op_names(text))
-            result.update(reduce(trace.load(path), names, steps=steps), steps=steps)
-            shutil.rmtree(self.dir, ignore_errors=True)
-            raise _WindowDone
+    from bench import harness
 
     cfg, tr = cell["config"], cell["traffic"]
     dev = harness.device_check(cell["chips"], require_tpu=True)
-    runner = {"train": harness.run_train, "decode": harness.run_decode}[tr["kind"]]
-    with _compiled_texts(texts), contextlib.suppress(_WindowDone):
-        runner(harness.model_dict(cfg), cfg, tr, seed, seconds, Tracer(True, cell["name"]),
-               harness.CompileCounter.get(), dev, time.perf_counter, False)
-    return result
+    tracer = harness.Tracer(True, cell["name"], keep=keep)
+    with tracer.programs():
+        _, record, *_ = harness.RUNNERS[tr["kind"]](
+            harness.model_dict(cfg), cfg, tr, seed, seconds, tracer,
+            harness.CompileCounter.get(), dev, time.perf_counter, False)
+    return {**record["trace"]["scopes"], "steps": record["steps"]}
 
 
 def main() -> None:
@@ -376,9 +372,7 @@ def main() -> None:
     from bench import harness
 
     cell = harness.load_cell(args.workload)
-    red = run(cell, args.seed, args.seconds, args.keep)
-    harness.log(f"{args.workload}: device time per step by scope over {red['steps']} steps")
-    harness.log(table(red))
+    red = run(cell, args.seed, args.seconds, args.keep)  # logs the table
     print(json.dumps({"workload": args.workload, "seed": args.seed, **red}), flush=True)
 
 

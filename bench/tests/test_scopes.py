@@ -240,7 +240,7 @@ def _instructions(text: str) -> list[str]:
 def steps():
     texts = []
     lowered = _lowered_steps()
-    with scopes._compiled_texts(texts):
+    with scopes.compiled_texts(texts):
         compiled = [low.compile() for low in lowered]
     assert texts == [c.as_text() for c in compiled]
     with pytest.MonkeyPatch.context() as mp:
@@ -282,7 +282,7 @@ def test_the_scopes_change_nothing_but_metadata(steps, which):
 
 def test_the_programs_are_compiled_under_a_key_that_holds_their_metadata():
     before = jax.config.jax_compilation_cache_include_metadata_in_key
-    with scopes._compiled_texts([]):
+    with scopes.compiled_texts([]):
         assert jax.config.jax_compilation_cache_include_metadata_in_key
     assert jax.config.jax_compilation_cache_include_metadata_in_key == before
 
@@ -291,3 +291,51 @@ def test_the_programs_are_compiled_under_a_key_that_holds_their_metadata():
 def test_no_host_callback_in_the_compiled_steps(steps, which):
     targets = re.findall(r'custom_call_target="([^"]*)"', steps["texts"][which])
     assert not [t for t in targets if "callback" in t], targets
+
+
+# ---------------------------------------------------------------------------
+# the traced run's record and the per-layer metrics that read it
+# ---------------------------------------------------------------------------
+
+
+def test_a_traced_record_carries_device_time_by_scope():
+    tracer = harness.Tracer(True, "tiny")
+    tracer.texts.append(HLO)
+    pd = _trace([{"XLA Ops": OPS, "XLA Modules": MODULES}])
+    red = tracer.summarise(pd, steps=2)
+    assert red["window_s"] == pytest.approx(20e-3)  # bench.trace.reduce's own
+    assert red["scopes"] == scopes.reduce(pd, scopes.op_names(HLO), steps=2)
+    assert red["scopes"]["scopes"]["mamba.ssd"] == pytest.approx(4.5e-3 / 2)
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_only_a_traced_run_compiles_under_a_key_with_metadata(on):
+    before = jax.config.jax_compilation_cache_include_metadata_in_key
+    tracer = harness.Tracer(on, "tiny")
+    with tracer.programs():
+        assert jax.config.jax_compilation_cache_include_metadata_in_key == (on or before)
+        jax.jit(lambda x: x + 1).lower(1.0).compile()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key == before
+    assert len(tracer.texts) == (1 if on else 0)
+
+
+#: metric -> (cell kind, scope it reads)
+SCOPE_METRICS = {"ssd_ms.train": ("train", "mamba.ssd"),
+                 "optimizer_ms.train": ("train", "optimizer"),
+                 "ssm_update_ms.decode": ("decode", "mamba.ssm_update"),
+                 "weights_ms.decode": ("decode", "serve.weights"),
+                 "layer_scan_ms.decode": ("decode", "layers")}
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_a_scope_metric_reads_its_scope_in_ms_per_step(metric):
+    kind, scope = SCOPE_METRICS[metric]
+    times = {s: 1e-3 * (i + 1) for i, (_, s) in enumerate(SCOPE_METRICS.values())}
+    record = {"kind": kind, "trace": {"scopes": {"scopes": times}}}
+    assert harness.read_metric(metric, record) == pytest.approx(1e3 * times[scope])
+    del times[scope]
+    assert harness.read_metric(metric, record) is None
+    other = "decode" if kind == "train" else "train"
+    assert harness.read_metric(metric, {"kind": other, "trace": {"scopes": {"scopes": {
+        scope: 1.0}}}}) is None
+    assert harness.read_metric(metric, {"kind": kind, "trace": None}) is None

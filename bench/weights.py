@@ -1,12 +1,10 @@
 """Seeded random weights for a configuration, made by the benchmark.
 
 Each parameter is named as the benchmark names it (``layers.attn.wq``) and
-drawn from its own key: ``fold_in(seed key, crc32(name))``, and for a
-per-layer parameter ``fold_in(that, layer)``.  So one jitted call makes the
-whole stack on the device, and the reference can make layer ``l`` alone and
-get the same numbers.  The distributions follow the repository's model
-family conventions (normal matrices scaled by ``fan_in ** -0.5``, zero norm
-gains, Mamba-2's ``A``, ``dt`` bias and ``D``).
+drawn from its own key: ``fold_in(seed key, crc32(name))``, and each slice
+of a stacked parameter from ``fold_in(that, index)``.  So one jitted call
+makes the whole stack on the device.  Which parameters a configuration has,
+their shapes and distributions, is its family's (``bench/families/``).
 """
 
 from __future__ import annotations
@@ -17,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.work import ssm_dims
+from bench import families
 
 
 def seed_key(seed: int, stream: str):
@@ -32,39 +30,13 @@ def seed_key(seed: int, stream: str):
 
 
 def spec(c) -> dict:
-    """name -> (shape, init, stacked) for every parameter of config ``c``.
+    """name -> (shape, init, stacked) for every parameter of config ``c``,
+    as its family (``bench/families/<family>.py``) lists them.
 
     ``init`` is ("normal", scale) | ("zeros",) | ("ones",) | ("full", value)
-    | ("a_log",); ``stacked`` parameters have a leading layer axis.
+    | ("a_log",); ``stacked`` parameters have a leading stack axis.
     """
-    d, L, V = c["d_model"], c["num_layers"], c["vocab_size"]
-    s = {"embed": ((V, d), ("normal", d ** -0.5), False)}
-    s["layers.ln1"] = ((L, d), ("zeros",), True)
-    if c["family"] != "ssm":
-        H, Kv, hd, f = c["num_heads"], c["num_kv_heads"], c["head_dim"], c["d_ff"]
-        s["layers.ln2"] = ((L, d), ("zeros",), True)
-        s["layers.attn.wq"] = ((L, d, H * hd), ("normal", d ** -0.5), True)
-        s["layers.attn.wk"] = ((L, d, Kv * hd), ("normal", d ** -0.5), True)
-        s["layers.attn.wv"] = ((L, d, Kv * hd), ("normal", d ** -0.5), True)
-        s["layers.attn.wo"] = ((L, H * hd, d), ("normal", (H * hd) ** -0.5), True)
-        s["layers.mlp.wi"] = ((L, d, f), ("normal", d ** -0.5), True)
-        s["layers.mlp.wg"] = ((L, d, f), ("normal", d ** -0.5), True)
-        s["layers.mlp.wo"] = ((L, f, d), ("normal", f ** -0.5), True)
-    if c["family"] in ("ssm", "hybrid"):
-        m = ssm_dims(c)
-        din, nh, N, w, F = m["d_in"], m["nh"], m["N"], m["w"], m["F"]
-        s["layers.ssm.in_proj"] = ((L, d, 2 * din + 2 * N + nh), ("normal", d ** -0.5), True)
-        s["layers.ssm.conv_w"] = ((L, w, F), ("normal", 0.2), True)
-        s["layers.ssm.conv_b"] = ((L, F), ("zeros",), True)
-        s["layers.ssm.a_log"] = ((L, nh), ("a_log",), True)
-        s["layers.ssm.dt_bias"] = ((L, nh), ("full", -4.6), True)
-        s["layers.ssm.D"] = ((L, nh), ("ones",), True)
-        s["layers.ssm.norm_g"] = ((L, din), ("zeros",), True)
-        s["layers.ssm.out_proj"] = ((L, din, d), ("normal", din ** -0.5), True)
-    s["final_norm"] = ((d,), ("zeros",), False)
-    if not c["tie_embeddings"]:
-        s["lm_head"] = ((d, V), ("normal", d ** -0.5), False)
-    return s
+    return families.load(c).spec(c)
 
 
 def _draw(init, key, shape):
@@ -86,19 +58,14 @@ def _leaf_key(key, name):
     return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
 
 
-def make(c, key, layer: int | None = None, names=None) -> dict:
-    """Weights as a flat dict of f32 arrays.  With ``layer`` given, only the
-    per-layer parameters, each without its layer axis.  Traceable: call it
-    inside ``jax.jit``."""
+def make(c, key, names=None) -> dict:
+    """Weights as a flat dict of f32 arrays (only ``names``, where given).
+    Traceable: call it inside ``jax.jit``."""
     out = {}
     for name, (shape, init, stacked) in spec(c).items():
         if names is not None and name not in names:
             continue
         k = _leaf_key(key, name)
-        if layer is not None:
-            if stacked:
-                out[name] = _draw(init, jax.random.fold_in(k, layer), shape[1:])
-            continue
         if stacked:
             ks = jax.vmap(lambda l: jax.random.fold_in(k, l))(jnp.arange(shape[0]))
             out[name] = jax.vmap(lambda kk: _draw(init, kk, shape[1:]))(ks)

@@ -5,7 +5,9 @@ These are the yardstick's counts, independent of how the program implements
 a step: recomputation, masked blocks, padding and dequantised copies do not
 count.  A configuration dict has the repository's ``ModelConfig`` field
 names (``num_layers``, ``d_model``, ...), as ``bench/configs/*.json`` hold
-them.
+them.  Its family (``bench/families/<family>.py``) says what each layer
+computes, as a :class:`Layer`, and how many prefix positions precede every
+sequence; the sums here are the same for every family.
 
 Conventions:
 
@@ -15,15 +17,31 @@ Conventions:
 * the depthwise convolution of the SSM is counted with the matmuls (its
   ``w x F`` weights are one multiply-add each per token);
 * the embedding lookup is no matmul and is not counted; the output head is;
-* attention at position ``i`` attends ``min(i + 1, window)`` keys: 4
-  operations per key and query head dimension (scores and the weighted
-  sum), 12 in training;
+* in layer ``l``, with window ``w_l`` (0: global) and a prefix of ``P``
+  positions that every query attends whatever its window (meta tokens),
+  a query at position ``i`` attends ``min(i + 1, w_l) + P`` keys
+  (``i + 1 + P`` in a global layer): 4 operations per key and query head
+  dimension (scores and the weighted sum), 12 in training;
+* in training the prefix runs through the stack with every sequence: its
+  ``P`` positions' matmul and SSM operations are counted with the
+  sequence's (the head excepted), and prefix position ``j`` attends
+  ``j + 1`` keys, all spread over the sequence's tokens;
+* in decode the prefix is in the cache.  Each layer that attends reads the
+  K/V of its keys, so K/V that layers share are counted once per reading
+  layer (a layer's queries exist only once the layer before it has run);
+  only the layer that stores K/V writes the new position;
 * the SSM is counted as its linear recurrence, 5 operations per state
   element and token (decay, input outer product and add: 3; the readout
   contraction: 2), 15 in training.
 """
 
 from __future__ import annotations
+
+import dataclasses
+
+import numpy as np
+
+from bench import families, weights
 
 #: wire format -> stored bytes per element (block-scaled formats carry one
 #: scale byte per 32 elements)
@@ -33,6 +51,26 @@ FORMAT_BYTES = {
 }
 
 
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    """What one decoder layer computes, per token and sequence."""
+
+    matmul: int  # parameters that take part in a matmul-like product
+    heads: int = 0  # query heads of its attention; 0: it does not attend
+    head_dim: int = 0
+    kv: int = 0  # K and V elements the cache holds per position
+    window: int = 0  # keys a query reaches back; 0: global
+    stores_kv: bool = True  # False: attends another layer's K/V, stores none
+    ssm_state: int = 0  # SSM state elements per sequence
+    conv_state: int = 0  # conv tail elements per sequence
+
+
+@dataclasses.dataclass(frozen=True)
+class Stack:
+    layers: tuple[Layer, ...]
+    prefix: int = 0  # positions every query attends, whatever its window
+
+
 def format_bytes(fmt: str) -> float:
     try:
         return FORMAT_BYTES[fmt]
@@ -40,35 +78,13 @@ def format_bytes(fmt: str) -> float:
         raise KeyError(f"bench/work.py has no byte count for format {fmt!r}") from None
 
 
-def _has_attention(c) -> bool:
-    return c["family"] != "ssm"
+def stack(c) -> Stack:
+    return families.load(c).stack(c)
 
 
-def _has_ssm(c) -> bool:
-    return c["family"] in ("ssm", "hybrid")
-
-
-def ssm_dims(c) -> dict:
-    """Inner width, heads, state size, head dim, conv width and conv features."""
-    d_in = c["ssm_expand"] * c["d_model"] if c["family"] == "ssm" else c["d_model"]
-    hd = c["ssm_head_dim"]
-    return {"d_in": d_in, "nh": d_in // hd, "N": c["ssm_state"], "hd": hd,
-            "w": c["ssm_conv_width"], "F": d_in + 2 * c["ssm_state"]}
-
-
-def layer_matmul_params(c) -> int:
-    """Parameters of one layer that take part in a matmul-like product."""
-    d = c["d_model"]
-    n = 0
-    if _has_attention(c):
-        H, Kv, hd = c["num_heads"], c["num_kv_heads"], c["head_dim"]
-        n += d * H * hd + 2 * d * Kv * hd + H * hd * d
-        n += 3 * d * c["d_ff"]  # SwiGLU
-    if _has_ssm(c):
-        s = ssm_dims(c)
-        n += d * (2 * s["d_in"] + 2 * s["N"] + s["nh"]) + s["d_in"] * d
-        n += s["w"] * s["F"]  # depthwise conv
-    return n
+def layer_matmul_params(c, i: int = 0) -> int:
+    """Parameters of layer ``i`` that take part in a matmul-like product."""
+    return stack(c).layers[i].matmul
 
 
 def head_params(c) -> int:
@@ -76,7 +92,7 @@ def head_params(c) -> int:
 
 
 def matmul_params(c) -> int:
-    return c["num_layers"] * layer_matmul_params(c) + head_params(c)
+    return sum(layer.matmul for layer in stack(c).layers) + head_params(c)
 
 
 def mean_keys(seq: int, window: int) -> float:
@@ -87,58 +103,51 @@ def mean_keys(seq: int, window: int) -> float:
     return (window * (window + 1) / 2 + (seq - window) * window) / seq
 
 
-def _keys(c, live: float) -> float:
-    """Keys a decode query attends when ``live`` positions exist."""
-    w = c.get("sliding_window", 0)
-    return min(live, w) if w > 0 else live
+def _keys(layer: Layer, live: float, prefix: int) -> float:
+    """Keys a decode query of ``layer`` attends when ``live`` positions exist."""
+    return (min(live, layer.window) if layer.window > 0 else live) + prefix
 
 
-def attn_flops_per_key(c) -> int:
+def _flops_per_key(layer: Layer) -> int:
     """Forward operations per (query, key) pair summed over heads."""
-    return 4 * c["num_heads"] * c["head_dim"] if _has_attention(c) else 0
-
-
-def ssm_state_elems(c) -> int:
-    if not _has_ssm(c):
-        return 0
-    s = ssm_dims(c)
-    return s["nh"] * s["N"] * s["hd"]
+    return 4 * layer.heads * layer.head_dim
 
 
 def train_flops_per_token(c, seq: int) -> float:
     """Model operations per trained token (forward and backward)."""
-    L = c["num_layers"]
-    f = 6.0 * matmul_params(c)
-    f += 3.0 * L * attn_flops_per_key(c) * mean_keys(seq, c.get("sliding_window", 0))
-    f += 15.0 * L * ssm_state_elems(c)
+    s = stack(c)
+    P = s.prefix
+    rows = (seq + P) / seq  # positions through the stack per trained token
+    f = 6.0 * (sum(layer.matmul for layer in s.layers) * rows + head_params(c))
+    keys = lambda w: mean_keys(seq, w) + P + P * (P + 1) / (2 * seq)
+    f += sum(3.0 * _flops_per_key(layer) * keys(layer.window) for layer in s.layers if layer.heads)
+    f += 15.0 * sum(layer.ssm_state for layer in s.layers) * rows
     return f
 
 
 def decode_flops_per_step(c, batch: int, live: int) -> float:
     """Operations of one decode step of ``batch`` sequences whose newest
     token sits at position ``live - 1`` (so ``live`` positions exist)."""
-    L = c["num_layers"]
-    keys = _keys(c, live)
+    s = stack(c)
     per_seq = 2.0 * matmul_params(c)
-    per_seq += L * attn_flops_per_key(c) * keys
-    per_seq += 5.0 * L * ssm_state_elems(c)
+    per_seq += sum(_flops_per_key(layer) * _keys(layer, live, s.prefix)
+                   for layer in s.layers if layer.heads)
+    per_seq += 5.0 * sum(layer.ssm_state for layer in s.layers)
     return batch * per_seq
 
 
 def param_count(c) -> dict:
     """Parameters by where a decode step reads them: ``embed`` (the table),
     ``stacked`` (every per-layer leaf) and ``head``/``final`` (the output
-    projection unless tied, and the final norm)."""
-    d, L = c["d_model"], c["num_layers"]
-    per = layer_matmul_params(c) - (ssm_dims(c)["w"] * ssm_dims(c)["F"] if _has_ssm(c) else 0)
-    per += d  # ln1
-    if _has_attention(c):
-        per += d  # ln2
-    if _has_ssm(c):
-        s = ssm_dims(c)
-        per += s["w"] * s["F"] + s["F"] + 3 * s["nh"] + s["d_in"]  # conv w, b; a, dt, D; norm
-    return {"embed": c["vocab_size"] * d, "stacked": L * per,
-            "head": 0 if c["tie_embeddings"] else head_params(c), "final": d}
+    projection unless tied, and the final norm).  Any other leaf (a
+    prefix's embeddings) is read in prefill only."""
+    out = {"embed": 0, "stacked": 0, "head": 0, "final": 0}
+    group = {"embed": "embed", "lm_head": "head", "final_norm": "final"}
+    for name, (shape, _, stacked) in weights.spec(c).items():
+        key = "stacked" if stacked else group.get(name)
+        if key:
+            out[key] += int(np.prod(shape))
+    return out
 
 
 def decode_bytes_per_step(c, wire: dict, batch: int, live: int,
@@ -146,25 +155,22 @@ def decode_bytes_per_step(c, wire: dict, batch: int, live: int,
     """Essential HBM bytes of one decode step.
 
     Weights in ``wire["weights"]`` read once (of the embedding table only the
-    ``batch`` rows looked up, unless it is also the tied head); the K/V of the
-    positions attention needs, ``min(live, window)`` per layer, in
-    ``wire["kv_cache"]``, read, plus the new position written; SSM and conv
-    state read and written at their stored bytes per element; f32 logits
-    written.
+    ``batch`` rows looked up, unless it is also the tied head); in each layer
+    that attends, the K/V of its keys in ``wire["kv_cache"]`` read, plus the
+    new position written where the layer stores K/V; SSM and conv state
+    read and written at their stored bytes per element; f32 logits written.
     """
-    L, d = c["num_layers"], c["d_model"]
+    s = stack(c)
     wb = format_bytes(wire["weights"])
     p = param_count(c)
     total = (p["stacked"] + p["head"]) * wb + p["final"] * 4.0
-    total += (p["embed"] if c["tie_embeddings"] else batch * d) * wb
-    if _has_attention(c):
-        keys = _keys(c, live)
-        per_pos = 2 * c["num_kv_heads"] * c["head_dim"] * format_bytes(wire["kv_cache"])
-        total += L * batch * (keys + 1) * per_pos  # keys read, one written
-    if _has_ssm(c):
-        s = ssm_dims(c)
-        total += 2 * L * batch * s["nh"] * s["N"] * s["hd"] * ssm_bytes
-        total += 2 * L * batch * (s["w"] - 1) * s["F"] * conv_bytes
+    total += (p["embed"] if c["tie_embeddings"] else batch * c["d_model"]) * wb
+    kvb = format_bytes(wire["kv_cache"])
+    for layer in s.layers:
+        if layer.heads:
+            total += batch * (_keys(layer, live, s.prefix) + layer.stores_kv) * layer.kv * kvb
+    total += 2 * batch * sum(layer.ssm_state for layer in s.layers) * ssm_bytes
+    total += 2 * batch * sum(layer.conv_state for layer in s.layers) * conv_bytes
     total += batch * c["vocab_size"] * 4.0
     return total
 
